@@ -42,11 +42,9 @@ import repro
 from repro.core import fra
 from repro.core.autodiff import ra_autodiff
 from repro.core.engine import StreamedCompiled, engine_for
-from repro.core.kernels import ADD, MUL, SQUARE, SUM_CHUNK, scale_kernel
-from repro.core.keys import EMPTY_KEY, TRUE, L, eq_pred, identity_key, jproj
 from repro.core.relation import DenseRelation
 from repro.launch.mesh import make_host_mesh
-from repro.relational.gcn import partitioned_edges
+from repro.relational.gcn import gcn_square_loss, partitioned_edges
 
 from .common import record, timeit
 
@@ -56,22 +54,6 @@ GRAPHS = [
     ("arxiv-mini", 2_000, 160_000, 32),
     ("pubmed-mini", 500, 20_000, 16),
 ]
-
-
-def _gcn_prog(n: int):
-    conv = fra.Agg(
-        identity_key(1), ADD,
-        fra.Join(
-            eq_pred((0, 0)), jproj(L(1)), MUL,
-            fra.scan("Edge", 2), fra.scan("Node", 1),
-        ),
-    )
-    sq = fra.Select(TRUE, identity_key(1), SQUARE, conv)
-    loss = fra.Agg(
-        EMPTY_KEY, ADD, fra.Select(TRUE, identity_key(1), SUM_CHUNK, sq)
-    )
-    mean = fra.Select(TRUE, identity_key(0), scale_kernel(1.0 / n), loss)
-    return ra_autodiff(fra.Query(mean, inputs=("Edge", "Node")))
 
 
 def _env(rng, n: int, e: int, d: int, num_shards: int):
@@ -115,7 +97,7 @@ def run() -> None:
             )
             continue
         env = _env(rng, n, e, d, n_dev)
-        prog = _gcn_prog(n)
+        prog = ra_autodiff(gcn_square_loss(n))
         eng = engine_for(prog)
         low = eng.lower(env)
 

@@ -29,15 +29,10 @@ import numpy as np
 from repro.core import fra
 from repro.core.autodiff import ra_autodiff
 from repro.core.engine import engine_for
-from repro.core.kernels import (
-    ADD,
-    MUL,
-    make_table,
-    resolve_impl,
-    scale_kernel,
-)
-from repro.core.keys import EMPTY_KEY, TRUE, L, eq_pred, identity_key, jproj
+from repro.core.kernels import make_table, resolve_impl, scale_kernel
+from repro.core.keys import TRUE, identity_key
 from repro.core.relation import CooRelation, DenseRelation
+from repro.relational.gcn import gcn_square_loss
 
 from .common import record, timeit
 from .logreg import logreg_query
@@ -72,21 +67,7 @@ def _logreg_env(rng, n: int, m: int):
 
 
 def _gcn_prog(n: int):
-    from repro.core.kernels import SQUARE, SUM_CHUNK
-
-    conv = fra.Agg(
-        identity_key(1), ADD,
-        fra.Join(
-            eq_pred((0, 0)), jproj(L(1)), MUL,
-            fra.const("Edge", 2), fra.scan("Node", 1),
-        ),
-    )
-    sq = fra.Select(TRUE, identity_key(1), SQUARE, conv)
-    loss = fra.Agg(
-        EMPTY_KEY, ADD, fra.Select(TRUE, identity_key(1), SUM_CHUNK, sq)
-    )
-    mean = fra.Select(TRUE, identity_key(0), scale_kernel(1.0 / n), loss)
-    return ra_autodiff(fra.Query(mean, inputs=("Node",)))
+    return ra_autodiff(gcn_square_loss(n, const_edges=True))
 
 
 def _gcn_env(rng, n: int, e: int, d: int):

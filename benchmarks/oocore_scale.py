@@ -27,11 +27,9 @@ from __future__ import annotations
 import numpy as np
 
 import repro
-from repro.core import fra
 from repro.core.engine import StreamedCompiled
-from repro.core.kernels import ADD, MUL, SQUARE, SUM_CHUNK, scale_kernel
-from repro.core.keys import EMPTY_KEY, TRUE, L, eq_pred, identity_key, jproj
 from repro.core.planner import _rel_bytes
+from repro.relational.gcn import gcn_square_loss
 
 from .common import record, timeit
 
@@ -43,22 +41,6 @@ GRAPHS = [
     ("pubmed-mini", 500, 20_000, 16),
     ("arxiv-mini", 1_000, 80_000, 32),
 ]
-
-
-def _gcn_query(n: int) -> fra.Query:
-    conv = fra.Agg(
-        identity_key(1), ADD,
-        fra.Join(
-            eq_pred((0, 0)), jproj(L(1)), MUL,
-            fra.scan("Edge", 2), fra.scan("Node", 1),
-        ),
-    )
-    sq = fra.Select(TRUE, identity_key(1), SQUARE, conv)
-    loss = fra.Agg(
-        EMPTY_KEY, ADD, fra.Select(TRUE, identity_key(1), SUM_CHUNK, sq)
-    )
-    mean = fra.Select(TRUE, identity_key(0), scale_kernel(1.0 / n), loss)
-    return fra.Query(mean, inputs=("Edge", "Node"))
 
 
 def _fill(db, rng, n: int, e: int, d: int):
@@ -90,7 +72,7 @@ def _leaves(loss, grads):
 
 def run() -> None:
     for seed, (name, n, e, d) in enumerate(GRAPHS, start=17):
-        q = _gcn_query(n)
+        q = gcn_square_loss(n)
         wrt = ("Edge", "Node")
 
         db0 = _fill(repro.Database(), np.random.default_rng(seed), n, e, d)

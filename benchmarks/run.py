@@ -29,6 +29,9 @@ from .common import ROWS, emit_header, emit_json
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         coo_scale,
         engine_overhead,

@@ -55,7 +55,8 @@ class ModelConfig:
                                        # this length (parallel prefix within)
     ssm_scan_dtype: str = "float32"    # state dtype inside the scan
     ssm_pallas: bool = False           # use the Pallas single-pass scan
-                                       # kernel (TPU; interpret on CPU)
+                                       # kernel (TPU; on CPU only under
+                                       # pltpu.force_tpu_interpret_mode)
 
     # encoder-decoder (whisper)
     encoder_layers: int = 0

@@ -538,6 +538,9 @@ class Lowered:
         #: compile_auto's plan record: per (mesh, donate, …) base key the
         #: Compiled whose committed-layout plan the catalog stands by.
         self._auto: "OrderedDict[Tuple, Compiled]" = OrderedDict()
+        #: this lowering re-resolved under the tables ``compile`` derives
+        #: for multi-device meshes (``kernels.table_for_mesh``).
+        self._retabled: Dict[kernels.DispatchTable, "Lowered"] = {}
 
     def eager(self, env: Env, seed: Optional[AnyRel] = None):
         """Un-jitted execution (re-walks the graph; debugging only)."""
@@ -587,7 +590,22 @@ class Lowered:
         Agg-size / edge-cut heuristics with measured key-domain
         statistics. The snapshot is part of the compile cache key —
         refreshed statistics re-plan, identical ones hit the cache.
+        On a mesh of more than one device the step runs under
+        ``kernels.table_for_mesh(self.dispatch, mesh)`` (no ``pallas``
+        tier), and the returned Compiled's ``dispatch``/``resolutions``
+        say so.
         """
+        table = kernels.table_for_mesh(self.dispatch, mesh)
+        if table != self.dispatch:
+            return self._relowered(table).compile(
+                mesh,
+                axis=axis,
+                donate=donate,
+                mem_budget=mem_budget,
+                n_devices=n_devices,
+                committed=committed,
+                stats=stats,
+            )
         donate = tuple(sorted(donate))
         geo = (
             planner.MeshGeometry.from_mesh(mesh, axis=axis)
@@ -709,6 +727,38 @@ class Lowered:
         while len(self._compiled) > _MAX_COMPILED:
             self._compiled.popitem(last=False)
         return compiled
+
+    def _relowered(self, table: kernels.DispatchTable) -> "Lowered":
+        """This lowering re-resolved under another dispatch table: the same
+        program, signature and validate report, with the kernel sites
+        resolved (and recorded) afresh."""
+        low = self._retabled.get(table)
+        if low is None:
+            resolutions = kernels.ResolutionLog()
+            jax.eval_shape(
+                functools.partial(
+                    self.engine._execute,
+                    dispatch=table,
+                    resolutions=resolutions,
+                    program=self.program,
+                ),
+                self.abstract_env,
+                self.abstract_seed,
+            )
+            low = Lowered(
+                self.engine,
+                self.sig,
+                table,
+                self.abstract_env,
+                self.abstract_seed,
+                self.out_shape,
+                resolutions,
+                program=self.program,
+                rewrite_report=self.rewrite_report,
+                check_report=self.check_report,
+            )
+            self._retabled[table] = low
+        return low
 
     def compile_auto(
         self,
@@ -1277,22 +1327,22 @@ def engine_for(program: Program, *, fuse_join_agg: bool = True) -> RAEngine:
     return eng
 
 
-def _trace_clean() -> bool:
-    """True outside any active jax trace. Meshes are only compiled
-    against at top level: an outer jit/grad's in-flight shardings would
-    fight the planner's, so sharding is left to propagate from the
-    traced operands instead. The one place this probe lives — the
-    session's mesh resolution reuses it."""
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # no trace-state probe on this jax:
-        return False  # be safe, skip the ambient mesh
+def _trace_clean(*trees) -> bool:
+    """True when no leaf of ``trees`` (a step's inputs) is a jax Tracer,
+    i.e. the step is not being traced by an outer jit/grad. Meshes are
+    only compiled against outside such traces: an outer trace's in-flight
+    shardings would fight the planner's, so sharding is left to propagate
+    from the traced operands instead. The one place this probe lives —
+    the session's mesh resolution reuses it."""
+    return not any(
+        isinstance(x, jax.core.Tracer) for x in jax.tree_util.tree_leaves(trees)
+    )
 
 
-def _ambient_mesh():
-    """The mesh a top-level staged execution should compile against: the
-    innermost ambient mesh, or None under an active trace."""
-    return default_mesh() if _trace_clean() else None
+def _ambient_mesh(*trees):
+    """The mesh a staged execution over ``trees`` should compile against:
+    the innermost ambient mesh, or None when the inputs are traced."""
+    return default_mesh() if _trace_clean(*trees) else None
 
 
 def _staged_execute(
@@ -1313,11 +1363,11 @@ def _staged_execute(
     Lowered, per-mesh ``compile_auto`` record (committed layouts folded
     without plan-flapping). The internal staged hot path
     ``Database.execute`` and the relational operator layer step through;
-    ``mesh=None`` picks up the ambient (session-activated) mesh outside
-    traces; ``rewrite`` enables the cost-gated rewrite stage (anything
+    ``mesh=None`` picks up the ambient (session-activated) mesh when the
+    inputs are not traced; ``rewrite`` enables the cost-gated rewrite stage (anything
     ``rewrite.make_rules`` accepts)."""
     if mesh is None:
-        mesh = _ambient_mesh()
+        mesh = _ambient_mesh(env, seed)
     eng = engine_for(program, fuse_join_agg=fuse_join_agg)
     compiled = eng.lower(
         env, seed, dispatch=dispatch, stats=stats, rewrite=rewrite

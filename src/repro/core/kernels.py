@@ -389,6 +389,22 @@ def default_table(backend: Optional[str] = None) -> DispatchTable:
     )
 
 
+def table_for_mesh(table: DispatchTable, mesh) -> DispatchTable:
+    """The table a step compiled against ``mesh`` runs under. XLA's SPMD
+    partitioner cannot split a Pallas TPU kernel (a Mosaic call must sit
+    inside a ``shard_map``), so on a mesh of more than one device the
+    ``pallas`` tier leaves every op's order and the rest of it applies:
+    the default TPU table becomes ``jnp`` for all three ops. The engine
+    applies this once, where ``Lowered.compile`` meets the mesh."""
+    if mesh is None or mesh.size <= 1:
+        return table
+    entries = tuple(
+        (op, tuple(t for t in tiers if t != "pallas") or ("jnp",))
+        for op, tiers in table.entries
+    )
+    return DispatchTable(table.backend, entries)
+
+
 def make_table(spec=None, backend: Optional[str] = None) -> DispatchTable:
     """Normalize a dispatch request into a DispatchTable.
 
@@ -964,9 +980,6 @@ register_impl("blocked_matmul", "sanitizer", _matmul_sanitizer, predicate=_is_fl
 register_impl("blocked_matmul", "ref", _matmul_ref)
 register_impl("blocked_matmul", "jnp", _matmul_jnp)
 
-# The gather DMA kernel's interpret tier is the CPU-tested path; the TPU
-# hardware tier shares it behind the registry pending tile tuning on real
-# devices (ROADMAP "tier predicates from measurements").
 register_impl(
     "gather_join", "pallas", _gather_pallas, backends=("tpu",), predicate=_is_float
 )

@@ -525,6 +525,20 @@ class Database:
             "serve": copy.deepcopy(self._counters["serve"]),
         }
 
+    def resolutions(self) -> Dict[str, Tuple[str, ...]]:
+        """``op[site] → tiers``: the sorted set of tiers each dispatch
+        site resolved to, over every live step this session compiled —
+        query handles' steps and the anonymous-environment steps of the
+        relational operator layer alike. Steps can share a site key and
+        still resolve it differently (one on a mesh, one not), so a key
+        may carry more than one tier (``QueryHandle.resolutions`` covers
+        one handle's last step)."""
+        out: Dict[str, set] = {}
+        for comp in list(self._compiled_refs):
+            for site, tier in comp.resolutions.items():
+                out.setdefault(site, set()).add(tier)
+        return {site: tuple(sorted(t)) for site, t in sorted(out.items())}
+
     # -- the active mesh ---------------------------------------------------
 
     @property
@@ -547,14 +561,15 @@ class Database:
         self._mesh = None if isinstance(mesh, str) else mesh
         return self
 
-    def _step_mesh(self):
-        """Mesh a step should compile against: the session mesh — or the
-        ambient mesh of an enclosing activated session — outside traces;
-        None under an active trace (the engine's ``_trace_clean`` probe
-        is the single source of that rule)."""
+    def _step_mesh(self, *trees):
+        """Mesh a step over inputs ``trees`` should compile against: the
+        session mesh — or the ambient mesh of an enclosing activated
+        session — when the inputs are concrete; None when an outer trace
+        is tracing them (the engine's ``_trace_clean`` probe is the single
+        source of that rule)."""
         if self.mesh is not None:
-            return self.mesh if _engine._trace_clean() else None
-        return _engine._ambient_mesh()
+            return self.mesh if _engine._trace_clean(*trees) else None
+        return _engine._ambient_mesh(*trees)
 
     @contextlib.contextmanager
     def activate(self):
@@ -745,7 +760,7 @@ class Database:
                     )
                     return wlow.compile_auto(
                         wave_env,
-                        mesh=self._step_mesh(),
+                        mesh=self._step_mesh(wave_env, wave_seed),
                         stats=wstats,
                         mem_budget=self.mem_budget,
                     )
@@ -773,7 +788,7 @@ class Database:
         )
         compiled = low.compile_auto(
             env,
-            mesh=self._step_mesh(),
+            mesh=self._step_mesh(env, seed),
             donate=donate,
             stats=stats,
             mem_budget=self.mem_budget,

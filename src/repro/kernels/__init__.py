@@ -12,7 +12,8 @@ to two kernels:
               on random-access memory writes the TPU does not have.
 
 Each kernel package has: <name>.py (pl.pallas_call + BlockSpec),
-ops.py (jit'd public wrapper with interpret fallback and a custom VJP so
+ops.py (jit'd public wrapper with an explicit ``interpret`` flag for the
+Pallas interpreter, and a custom VJP so
 reverse-mode AD differentiates through the Pallas forward), ref.py
 (pure-jnp oracle used by tests and served as the ``ref`` dispatch tier).
 

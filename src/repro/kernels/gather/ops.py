@@ -33,7 +33,7 @@ from repro.core.kernels import (
     VjpPair,
 )
 
-from .gather import gather_rows_pallas
+from .gather import ROWS_PER_CALL, gather_rows_pallas
 from .ref import gather_rows_ref
 
 
@@ -93,16 +93,15 @@ def gather_rows(
     table: jnp.ndarray,
     rows: jnp.ndarray,
     *,
-    interpret: bool | None = None,
+    interpret: bool,
     use_pallas: bool = True,
 ) -> jnp.ndarray:
     """Gather rows of ``table`` (N, D) at ``rows`` (E,) on the Pallas
     scalar-prefetch DMA kernel; ids outside ``[0, N)`` (COO padding)
-    produce zero rows. ``interpret=None`` auto-selects interpreter mode
-    off-TPU. Differentiable wrt ``table`` (custom VJP: same-tier
-    segment-sum scatter of the cotangent)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    produce zero rows. ``interpret=True`` runs the kernel in the Pallas
+    interpreter (CPU); ``False`` compiles it for the TPU. Differentiable
+    wrt ``table`` (custom VJP: same-tier segment-sum scatter of the
+    cotangent)."""
     return _jitted(table, rows.astype(jnp.int32), interpret, use_pallas)
 
 
@@ -112,30 +111,35 @@ def gather_rows(
 def _grid_model(
     info: Dict[str, Any], rows: Optional[Any] = None, **concrete: Any
 ) -> Optional[GridModel]:
-    """The scalar-prefetch launch geometry: one program per output row.
-    Statically the table block index is only known to lie in the clamp
-    range ``[0, N)`` (an Interval); the sanitizer passes the concrete
-    ``rows`` to sharpen it into the exact per-step DMA indices."""
+    """The scalar-prefetch launch geometry: one program per output row,
+    over the rows padded to a whole number of ``ROWS_PER_CALL`` launches
+    (the union of the launches' grids). Statically the table block index
+    is only known to lie in the clamp range ``[0, N)`` (an Interval); the
+    sanitizer passes the concrete ``rows`` to sharpen it into the exact
+    per-step DMA indices."""
     e, n, d = int(info["rows"]), int(info["num_rows"]), int(info["dim"])
     if e == 0 or n == 0 or d == 0:
         return None  # the zero-nnz guard short-circuits before the kernel
+    e += (-e) % min(e, ROWS_PER_CALL)
     if rows is not None:
         import numpy as np_mod
 
         safe = np_mod.clip(np_mod.asarray(rows), 0, n - 1)
+        safe = np_mod.pad(safe, (0, e - safe.shape[0]))
 
         def table_map(i):
-            return (int(safe[i]), 0)
+            return (int(safe[i]), 0, 0)
     else:
         span = Interval(0, n - 1)
 
         def table_map(i):
-            return (span, 0)
+            return (span, 0, 0)
 
+    # the kernel's (rows, 1, D) views; the squeezed row dim is a block of 1
     return GridModel(
         grid=(e,),
-        inputs=(BlockModel("table", (n, d), (1, d), table_map),),
-        output=BlockModel("out", (e, d), (1, d), lambda i: (i, 0)),
+        inputs=(BlockModel("table", (n, 1, d), (1, 1, d), table_map),),
+        output=BlockModel("out", (e, 1, d), (1, 1, d), lambda i: (i, 0, 0)),
         accumulator=None,
     )
 

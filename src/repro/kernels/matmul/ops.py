@@ -4,8 +4,8 @@
 
 ``blocked_matmul(x, y)`` pads both operands up to tile multiples and runs
 the MXU-tiled kernel (matmul.py); ``use_pallas=False`` short-circuits to
-the jnp oracle (ref.py). ``interpret=None`` auto-selects interpreter mode
-off-TPU (this container is CPU-only; TPU is the deployment target).
+the jnp oracle (ref.py). ``interpret=True`` runs the kernel in the Pallas
+interpreter (CPU tests); ``False`` compiles it for the TPU.
 
 The wrapper carries a ``jax.custom_vjp`` so reverse-mode AD differentiates
 *through* the Pallas forward, and — matching the paper's Fig. 4 optimized
@@ -82,15 +82,13 @@ def blocked_matmul(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool | None = None,
+    interpret: bool,
     use_pallas: bool = True,
 ) -> jnp.ndarray:
     """``x @ y`` via the MXU-tiled Pallas kernel, padding to tile
     multiples. ``bm``/``bn``/``bk`` are the output-row/output-col/
     contraction tile sizes. Differentiable (custom VJP: two blocked
     matmuls on the same tier)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _blocked_matmul(x, y, bm, bn, bk, interpret, use_pallas)
 
 

@@ -44,7 +44,7 @@ def _run(msg, seg, num_segments, bs, be, bd, interpret, use_pallas):
     sp = (-num_segments) % bs
     out = segment_sum_pallas(
         msg,
-        seg.astype(jnp.int32),
+        seg.astype(jnp.int32)[None, :],
         num_segments + sp,
         bs=bs,
         be=be,
@@ -90,19 +90,18 @@ def segment_sum(
     bs: int = 128,
     be: int = 512,
     bd: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool,
     use_pallas: bool = True,
 ) -> jnp.ndarray:
     """Segment-sum of ``msg`` (E, D) by ``seg`` (E,) into ``num_segments``
     rows, on the Pallas one-hot-matmul kernel.
 
-    ``interpret=None`` auto-selects interpreter mode off-TPU; ``bs``/``be``
-    /``bd`` are the segment/edge/feature tile sizes (ragged inputs are
-    padded up). Differentiable wrt ``msg`` (custom VJP: gather of the
-    cotangent at ``seg``).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU);
+    ``False`` compiles it for the TPU. ``bs``/``be``/``bd`` are the
+    segment/edge/feature tile sizes (ragged inputs are padded up).
+    Differentiable wrt ``msg`` (custom VJP: gather of the cotangent at
+    ``seg``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _segment_sum(
         msg, seg.astype(jnp.int32), num_segments, bs, be, bd, interpret, use_pallas
     )
@@ -125,7 +124,7 @@ def _grid_model(info: Dict[str, Any], **concrete: Any) -> Optional[GridModel]:
     return GridModel(
         grid=(spad // bs, d // bd, epad // be),
         inputs=(
-            BlockModel("seg", (epad,), (be,), lambda i, j, k: (k,)),
+            BlockModel("seg", (1, epad), (1, be), lambda i, j, k: (0, k)),
             BlockModel("msg", (epad, d), (be, bd), lambda i, j, k: (k, j)),
         ),
         output=BlockModel("out", (spad, d), (bs, bd), lambda i, j, k: (i, j)),

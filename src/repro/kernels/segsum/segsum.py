@@ -35,14 +35,17 @@ def _segsum_kernel(seg_ref, msg_ref, o_ref, acc_ref, *, bs: int, ne: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     i = pl.program_id(0)
-    seg = seg_ref[...]  # (be,) int32 segment ids of this edge tile
-    local = seg - i * bs
+    local = seg_ref[...] - i * bs  # (1, be) int32 ids of this edge tile
     onehot = (
-        local[None, :] == jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+        local == jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
     ).astype(jnp.float32)  # (bs, be)
+    # A Σ is a sum, not a product the caller asked for: at the default
+    # precision the MXU would round each message to bf16, so the dot
+    # always takes the f32 passes (the one-hot itself is exact in bf16).
     acc_ref[...] += jnp.dot(
         onehot, msg_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(pl.program_id(2) == ne - 1)
@@ -52,7 +55,7 @@ def _segsum_kernel(seg_ref, msg_ref, o_ref, acc_ref, *, bs: int, ne: int):
 
 def segment_sum_pallas(
     msg: jnp.ndarray,   # (E, D)
-    seg: jnp.ndarray,   # (E,) int32 in [0, num_segments) (pad with -1)
+    seg: jnp.ndarray,   # (1, E) int32 in [0, num_segments) (pad with -1)
     num_segments: int,
     *,
     bs: int = 128,
@@ -61,7 +64,7 @@ def segment_sum_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     e, d = msg.shape
-    assert seg.shape == (e,)
+    assert seg.shape == (1, e)
     assert e % be == 0 and num_segments % bs == 0, (e, be, num_segments, bs)
     bd = bd or d
     assert d % bd == 0
@@ -71,7 +74,9 @@ def segment_sum_pallas(
         functools.partial(_segsum_kernel, bs=bs, ne=ne),
         grid=(num_segments // bs, d // bd, ne),
         in_specs=[
-            pl.BlockSpec((be,), lambda i, j, k: (k,)),
+            # ids as a (1, be) row: a 1-D int32 block does not match the
+            # TPU's 1-D layout, a row of a 2-D array does
+            pl.BlockSpec((1, be), lambda i, j, k: (0, k)),
             pl.BlockSpec((be, bd), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bs, bd), lambda i, j, k: (i, j)),

@@ -31,11 +31,10 @@ def _scan_kernel(a_ref, b_ref, h_ref, state, *, bt: int):
     def _init():
         state[...] = jnp.zeros_like(state)
 
-    a = a_ref[0].astype(jnp.float32)   # (bt, bc, N)
-    b = b_ref[0].astype(jnp.float32)
-
+    # index the refs, not loaded values: a dynamic slice of a loaded
+    # value lowers to dynamic_slice, which the TPU compiler does not take
     def step(t, h):
-        h = a[t] * h + b[t]
+        h = a_ref[0, t].astype(jnp.float32) * h + b_ref[0, t].astype(jnp.float32)
         h_ref[0, t] = h.astype(h_ref.dtype)
         return h
 

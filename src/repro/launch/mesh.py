@@ -10,13 +10,28 @@ Defined as functions (never module-level constants) so importing this
 module never touches jax device state — jax locks the device count on
 first backend initialization, and only dryrun.py is allowed to force the
 512-placeholder-device configuration.
+
+Every mesh is built by ``auto_mesh``: its axes are ``Auto``, so XLA's
+partitioner propagates the planner's in_shardings through the step. (An
+``Explicit`` axis, ``jax.make_mesh``'s default, makes sharding part of
+the array type and refuses, for example, an einsum whose contracting
+dims are sharded — exactly the co-partition plan.)
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.planner import DATA_AXIS_NAMES
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the one constructor of
+    the meshes the planner compiles against."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,7 +39,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     pod=2 axis (2 pods = 512 chips) used for data parallelism."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -37,13 +52,13 @@ def make_host_mesh(model: int = 1):
         raise ValueError(f"make_host_mesh: model={model} must be >= 1")
     n = len(jax.devices())
     if n == 1 and model == 1:
-        return jax.make_mesh((1,), ("model",))
+        return auto_mesh((1,), ("model",))
     if n % model != 0:
         raise ValueError(
             f"make_host_mesh: {n} visible device(s) not divisible by "
             f"model={model}"
         )
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 def resolve_mesh(spec):

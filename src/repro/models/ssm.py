@@ -153,7 +153,7 @@ def mamba1_apply(
 
             h = ssm_scan(
                 da.astype(scan_dtype), bx.astype(scan_dtype),
-                256, 8, jax.default_backend() != "tpu", True,
+                256, 8, False, True,
             )
         else:
             h = selective_scan(
@@ -247,7 +247,7 @@ def mamba2_apply(
             h = ssm_scan(
                 da_full.reshape(hb, hs, hh, n * head_dim).astype(scan_dtype),
                 bx.reshape(hb, hs, hh, n * head_dim).astype(scan_dtype),
-                256, 8, jax.default_backend() != "tpu", True,
+                256, 8, False, True,
             ).reshape(bx.shape)
         else:
             h = selective_scan(

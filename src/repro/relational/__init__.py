@@ -8,5 +8,5 @@ RJP-generated plans instead of JAX's own AD through the engine.
 """
 
 from .linear import rel_linear, rel_matmul  # noqa: F401
-from .gcn import gcn_conv, partitioned_edges  # noqa: F401
+from .gcn import gcn_conv, gcn_square_loss, partitioned_edges  # noqa: F401
 from .embedding import rel_embed  # noqa: F401

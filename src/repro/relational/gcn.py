@@ -33,8 +33,8 @@ import numpy as np
 
 from repro.core import fra, session
 from repro.core.autodiff import ra_autodiff
-from repro.core.kernels import ADD, MUL
-from repro.core.keys import L, eq_pred, identity_key, jproj
+from repro.core.kernels import ADD, MUL, SQUARE, SUM_CHUNK, scale_kernel
+from repro.core.keys import EMPTY_KEY, TRUE, L, eq_pred, identity_key, jproj
 from repro.core.relation import CooRelation, DenseRelation, owner_partition
 
 
@@ -55,16 +55,37 @@ def partitioned_edges(
     return owner_partition(rel, num_shards, dim=1)
 
 
-@functools.cache
-def _gcn_prog():
+def _conv(edge):
+    """Σ_dst(Edge ⋈ Node): each edge's w · h_src, summed by dst."""
     join = fra.Join(
         eq_pred((0, 0)),        # edge.src == node.id
         jproj(L(1)),            # output keyed by dst
         MUL,                    # w · h_src (scalar × chunk)
-        fra.scan("Edge", 2),    # differentiable edge weights
+        edge,
         fra.scan("Node", 1),
     )
-    q = fra.Query(fra.Agg(identity_key(1), ADD, join), inputs=("Edge", "Node"))
+    return fra.Agg(identity_key(1), ADD, join)
+
+
+def gcn_square_loss(n_nodes=None, *, const_edges: bool = False) -> fra.Query:
+    """One graph convolution and a sum-of-squares loss as one query,
+    Σ_v conv(v)² — divided by ``n_nodes`` when it is given. Edge weights
+    are differentiable inputs, or constants with ``const_edges`` (Node
+    is then the only input)."""
+    edge = (fra.const if const_edges else fra.scan)("Edge", 2)
+    sq = fra.Select(TRUE, identity_key(1), SQUARE, _conv(edge))
+    loss = fra.Agg(
+        EMPTY_KEY, ADD, fra.Select(TRUE, identity_key(1), SUM_CHUNK, sq)
+    )
+    if n_nodes is not None:
+        loss = fra.Select(TRUE, identity_key(0), scale_kernel(1.0 / n_nodes), loss)
+    return fra.Query(loss, inputs=("Node",) if const_edges else ("Edge", "Node"))
+
+
+@functools.cache
+def _gcn_prog():
+    # differentiable edge weights
+    q = fra.Query(_conv(fra.scan("Edge", 2)), inputs=("Edge", "Node"))
     prog = ra_autodiff(q)
     scans = {s.name: s.id for s in q.root.table_scans()}
     return prog, scans

@@ -8,6 +8,11 @@ signature, so switching tiers invalidates the engine's lowering cache
 while re-using a tier hits it.
 """
 
+import pathlib
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +33,7 @@ from repro.core.keys import (
     project_key,
 )
 from repro.core.relation import CooRelation, DenseRelation
+from repro.relational.gcn import gcn_square_loss
 
 CPU_TIERS = ("jnp", "ref", "interpret")
 
@@ -200,18 +206,7 @@ def _logreg_prog_env():
 
 
 def _gcn_prog_env():
-    join = fra.Join(
-        eq_pred((0, 0)), jproj(L(1)), MUL,
-        fra.const("Edge", 2), fra.scan("Node", 1),
-    )
-    q = fra.Query(fra.Agg(identity_key(1), ADD, join), inputs=("Node",))
-    from repro.core.kernels import SQUARE, SUM_CHUNK
-
-    sq = fra.Select(TRUE, identity_key(1), SQUARE, q.root)
-    loss = fra.Agg(
-        EMPTY_KEY, ADD, fra.Select(TRUE, identity_key(1), SUM_CHUNK, sq)
-    )
-    prog = ra_autodiff(fra.Query(loss, inputs=("Node",)))
+    prog = ra_autodiff(gcn_square_loss(const_edges=True))
     rng = np.random.default_rng(3)
     n, nnz, d = 16, 40, 8
     src = rng.integers(0, n, size=nnz)
@@ -313,3 +308,110 @@ def test_compiled_steps_per_tier_are_independent_and_cached():
         comp_ref(env)
     assert eng.trace_count == walks
     assert eng.lower(env, dispatch="ref").compile() is comp_ref
+
+
+# ---------------------------------------------------------------------------
+# Multi-device meshes: no Pallas call reaches XLA's SPMD partitioner
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "size, want", [(None, ("pallas", "jnp")), (1, ("pallas", "jnp")), (4, ("jnp",))]
+)
+def test_table_for_mesh_drops_pallas_on_multi_device_meshes(size, want):
+    from types import SimpleNamespace
+
+    mesh = None if size is None else SimpleNamespace(size=size)
+    t = K.table_for_mesh(K.default_table("tpu"), mesh)
+    assert t.backend == "tpu"
+    for op in K.DISPATCH_OPS:
+        assert t.tiers(op) == want
+    # tiers other than pallas keep their order
+    forced = K.make_table(("pallas", "interpret"), backend="cpu")
+    kept = ("interpret",) if size == 4 else ("pallas", "interpret")
+    assert K.table_for_mesh(forced, mesh).tiers("segment_sum") == kept
+
+
+_MESH_TABLE_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import kernels as K
+    from repro.core.autodiff import ra_autodiff
+    from repro.core.engine import RAEngine
+    from repro.core.relation import CooRelation, DenseRelation
+    from repro.launch.mesh import make_host_mesh
+    from repro.relational import gcn_square_loss
+
+    prog = ra_autodiff(gcn_square_loss())
+    rng = np.random.default_rng(0)
+    n, nnz, d = 16, 64, 8
+    env = {
+        "Edge": CooRelation(
+            jnp.asarray(rng.integers(0, n, size=(nnz, 2)), jnp.int32),
+            jnp.asarray(rng.normal(size=nnz), jnp.float32), (n, n)),
+        "Node": DenseRelation(jnp.asarray(rng.normal(size=(n, d)), jnp.float32), 1),
+    }
+
+    # a TPU table lowers to Pallas sites (abstractly: nothing runs) ...
+    low = RAEngine(prog).lower(env, dispatch=K.make_table(None, backend="tpu"))
+    assert set(low.resolutions.values()) == {"pallas"}, low.resolutions
+    # ... and a four-device mesh compiles and runs them as jnp
+    comp = low.compile(mesh=make_host_mesh(model=1))
+    assert "pallas" not in comp.dispatch.describe(), comp.dispatch
+    assert set(comp.resolutions.values()) == {"jnp"}, comp.resolutions
+    out, grads = comp(env)
+    ref_out, ref_grads = RAEngine(prog).lower(env).compile()(env)
+    np.testing.assert_allclose(np.asarray(out.data), np.asarray(ref_out.data),
+                               rtol=1e-5, atol=1e-5)
+    for name in ("Edge", "Node"):
+        g, r = grads[name], ref_grads[name]
+        g = g.values if name == "Edge" else g.data
+        r = r.values if name == "Edge" else r.data
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-5, atol=1e-5)
+    print("MESH-TABLE-OK")
+    """
+)
+
+
+@pytest.mark.spmd
+def test_multi_device_compile_runs_jnp_tier_in_place_of_pallas():
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    r = subprocess.run(
+        [sys.executable, "-c", _MESH_TABLE_SCRIPT],
+        capture_output=True,
+        text=True,
+        env={
+            "PYTHONPATH": str(repo / "src"),
+            "PATH": "/usr/bin:/bin",
+            "JAX_PLATFORMS": "cpu",
+        },
+        cwd=str(repo),
+        timeout=600,
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "MESH-TABLE-OK" in r.stdout
+
+
+def test_database_resolutions_keep_every_tier_a_site_resolved_to():
+    import repro
+
+    _, env = _gcn_prog_env()
+    db = repro.Database(dispatch="jnp")
+    db.put("Edge", env["Edge"])
+    db.put("Node", env["Node"].data, keys=("node",))
+    first = db.query(gcn_square_loss())
+    first.step(wrt=("Node",))
+    db.dispatch = K.make_table("ref")
+    second = db.query(gcn_square_loss())
+    second.step(wrt=("Node",))
+    res = db.resolutions()
+    sites = [k for k in res if k.split("[", 1)[0] in K.DISPATCH_OPS]
+    assert sites, res
+    # both steps are live and share their site keys: neither hides the other
+    assert {res[k] for k in sites} == {("jnp", "ref")}, res
+    assert set(first.resolutions.values()) == {"jnp"}
+    assert set(second.resolutions.values()) == {"ref"}

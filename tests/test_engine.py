@@ -264,8 +264,9 @@ _SPMD_SCRIPT = textwrap.dedent(
     from repro.core.kernels import ADD, MATMUL, MUL
     from repro.core.keys import L, R, eq_pred, identity_key, jproj, project_key
     from repro.core.relation import CooRelation, DenseRelation
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = auto_mesh((8,), ("model",))
     rng = np.random.default_rng(0)
 
     # ---- blocked matmul: tiny budget forces the co-partition plan ----

@@ -94,8 +94,10 @@ def test_gemma3_prefill_decode_consistency_sliding_window():
 
 
 def test_ssm_pallas_flag_close_to_default():
-    """The Pallas scan path (interpret mode on CPU) agrees with the XLA
-    parallel-prefix path through the full falcon-mamba block stack."""
+    """The Pallas scan path (in the TPU interpreter on CPU) agrees with the
+    XLA parallel-prefix path through the full falcon-mamba block stack."""
+    from jax.experimental.pallas import tpu as pltpu
+
     cfg = get_config("falcon-mamba-7b").reduced()
     rng = np.random.default_rng(3)
     batch = batch_for(cfg, 1, 32, rng)
@@ -103,7 +105,8 @@ def test_ssm_pallas_flag_close_to_default():
     m1 = build_model(replace(cfg, ssm_pallas=True))
     params = m0.init(jax.random.PRNGKey(10))
     l0, _ = m0.train_logits(params, batch)
-    l1, _ = m1.train_logits(params, batch)
+    with pltpu.force_tpu_interpret_mode():
+        l1, _ = m1.train_logits(params, batch)
     np.testing.assert_allclose(
         np.asarray(l0, np.float32), np.asarray(l1, np.float32),
         rtol=1e-3, atol=1e-4,

@@ -24,15 +24,10 @@ import pytest
 from repro.analysis import certify_kernels, certify_registry
 from repro.analysis import kernelcheck
 from repro.analysis.diagnostics import CheckReport
-from repro.core import fra
 from repro.core import kernels as K
 from repro.core.autodiff import ra_autodiff
 from repro.core.engine import RAEngine
 from repro.core.kernels import (
-    ADD,
-    MUL,
-    SQUARE,
-    SUM_CHUNK,
     AccumModel,
     BlockModel,
     GridModel,
@@ -40,8 +35,8 @@ from repro.core.kernels import (
     SanitizerError,
     VjpPair,
 )
-from repro.core.keys import EMPTY_KEY, TRUE, L, eq_pred, identity_key, jproj
 from repro.core.relation import CooRelation, DenseRelation
+from repro.relational.gcn import gcn_square_loss
 
 GOLDEN = Path(__file__).parent / "golden" / "kernelcheck"
 
@@ -174,17 +169,7 @@ def test_cli_exits_clean():
 
 def _gcn_prog_env():
     """COO conv: exercises gather_join + segment_sum sites, fwd + grad."""
-    join = fra.Join(
-        eq_pred((0, 0)), jproj(L(1)), MUL,
-        fra.const("Edge", 2), fra.scan("Node", 1),
-    )
-    q = fra.Query(fra.Agg(identity_key(1), ADD, join), inputs=("Node",))
-    sq = fra.Select(TRUE, identity_key(1), SQUARE, q.root)
-    loss = fra.Agg(
-        EMPTY_KEY, ADD,
-        fra.Select(TRUE, identity_key(1), SUM_CHUNK, sq),
-    )
-    prog = ra_autodiff(fra.Query(loss, inputs=("Node",)))
+    prog = ra_autodiff(gcn_square_loss(const_edges=True))
     rng = np.random.default_rng(7)
     n, nnz, d = 16, 40, 8
     env = {

@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps, interpret=True, allclose vs ref.py."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -71,3 +73,18 @@ def test_segment_sum_empty_segments():
     got = segment_sum(msg, seg, 4, interpret=True)
     assert np.allclose(np.asarray(got)[0], 8.0)
     assert np.allclose(np.asarray(got)[1:], 0.0)
+
+
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+def test_segment_sum_kernel_dot_takes_f32_passes(ambient):
+    """The Σ kernel's one-hot dot asks for the f32 passes itself, at any
+    ambient matmul precision: at the TPU default the MXU would round each
+    message to bf16, and the Σ would no longer equal an f32 scatter-add."""
+    with jax.default_matmul_precision(ambient):
+        jaxpr = str(jax.make_jaxpr(
+            lambda m, s: segment_sum(m, s, 8, interpret=True)
+        )(jnp.ones((16, 4)), jnp.zeros((16,), jnp.int32)))
+    dots = re.findall(r"dot_general\[(.*?)preferred_element_type", jaxpr, re.S)
+    assert dots
+    for params in dots:
+        assert "precision=(Precision.HIGHEST, Precision.HIGHEST)" in params

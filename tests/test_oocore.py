@@ -29,14 +29,14 @@ from repro.core import fra
 from repro.core.chunkstore import ChunkStore, OutOfCoreError
 from repro.core.engine import StreamedCompiled
 from repro.core.kernels import (
-    ADD, EXP, MUL, SQERR, SQUARE, SUM_CHUNK, scale_kernel,
+    ADD, EXP, MUL, SQERR, SUM_CHUNK,
 )
 from repro.core.keys import (
     EMPTY_KEY, TRUE, L, eq_pred, identity_key, jproj,
 )
 from repro.core.planner import plan_waves, _rel_bytes
 from repro.core.relation import COO_PAD_KEY, CooRelation, DenseRelation
-from repro.relational.gcn import partitioned_edges
+from repro.relational.gcn import gcn_square_loss, partitioned_edges
 
 ATOL = 1e-5
 
@@ -78,22 +78,6 @@ def _logreg_handle(db):
 
 def _logreg_bytes(n=64, m=8):
     return n * m * 4 + n * 4 + m * 4
-
-
-def _gcn_query(n):
-    conv = fra.Agg(
-        identity_key(1), ADD,
-        fra.Join(
-            eq_pred((0, 0)), jproj(L(1)), MUL,
-            fra.scan("Edge", 2), fra.scan("Node", 1),
-        ),
-    )
-    sq = fra.Select(TRUE, identity_key(1), SQUARE, conv)
-    loss = fra.Agg(
-        EMPTY_KEY, ADD, fra.Select(TRUE, identity_key(1), SUM_CHUNK, sq)
-    )
-    mean = fra.Select(TRUE, identity_key(0), scale_kernel(1.0 / n), loss)
-    return fra.Query(mean, inputs=("Edge", "Node"))
 
 
 def _gcn_fill(db, n=60, e=500, d=8, seed=1, shards=4):
@@ -192,10 +176,10 @@ def test_logreg_chunked_matches_incore_across_wave_counts():
 def test_gcn_chunked_matches_incore():
     n = 60
     db0 = _gcn_fill(repro.Database(), n=n)
-    l0, g0 = db0.query(_gcn_query(n)).step(wrt=("Edge", "Node"))
+    l0, g0 = db0.query(gcn_square_loss(n)).step(wrt=("Edge", "Node"))
     total = _rel_bytes(db0.get("Edge")) + _rel_bytes(db0.get("Node"))
     db = _gcn_fill(repro.Database(memory_budget=total / 3), n=n)
-    h = db.query(_gcn_query(n))
+    h = db.query(gcn_square_loss(n))
     l1, g1 = h.step(wrt=("Edge", "Node"))
     assert isinstance(h.last, StreamedCompiled)
     assert h.last.num_waves >= 2
@@ -227,10 +211,10 @@ def test_kge_chunked_matches_incore(partition):
 def test_forward_only_query_streams_too():
     n = 60
     db0 = _gcn_fill(repro.Database(), n=n)
-    out0 = db0.query(_gcn_query(n)).forward()
+    out0 = db0.query(gcn_square_loss(n)).forward()
     total = _rel_bytes(db0.get("Edge")) + _rel_bytes(db0.get("Node"))
     db = _gcn_fill(repro.Database(memory_budget=total / 3), n=n)
-    h = db.query(_gcn_query(n))
+    h = db.query(gcn_square_loss(n))
     out1 = h.forward()
     assert isinstance(h.last, StreamedCompiled)
     np.testing.assert_allclose(
@@ -278,7 +262,7 @@ def test_budget_smaller_than_resident_raises():
     db = _gcn_fill(repro.Database(memory_budget=64.0))
     # resident Node alone exceeds 64 bytes: no wave count can help
     with pytest.raises(OutOfCoreError, match="too small"):
-        db.query(_gcn_query(60)).step(wrt=("Node",))
+        db.query(gcn_square_loss(60)).step(wrt=("Node",))
 
 
 def test_budget_needing_more_waves_than_rows_raises():
@@ -429,7 +413,7 @@ def test_gcn_4x_budget_waves_on_host_mesh():
 
     n, e, d = 200, 4000, 16
     db0 = _gcn_fill(repro.Database(), n=n, e=e, d=d, shards=8)
-    l0, g0 = db0.query(_gcn_query(n)).step(wrt=("Edge", "Node"))
+    l0, g0 = db0.query(gcn_square_loss(n)).step(wrt=("Edge", "Node"))
 
     edge_bytes = _rel_bytes(db0.get("Edge"))
     node_bytes = _rel_bytes(db0.get("Node"))
@@ -439,7 +423,7 @@ def test_gcn_4x_budget_waves_on_host_mesh():
         repro.Database(mesh=make_host_mesh(model=2), memory_budget=budget),
         n=n, e=e, d=d, shards=8,
     )
-    h = db.query(_gcn_query(n))
+    h = db.query(gcn_square_loss(n))
     l1, g1 = h.step(wrt=("Edge", "Node"))
     assert isinstance(h.last, StreamedCompiled)
     assert h.last.num_waves >= 4
@@ -507,7 +491,7 @@ def test_streamed_gcn_plan_certifies_owner_alignment():
     total = _rel_bytes(db0.get("Edge")) + _rel_bytes(db0.get("Node"))
     db = _gcn_fill(repro.Database(memory_budget=total / 3), n=n)
     env = {"Edge": db.get("Edge"), "Node": db.get("Node")}
-    h = db.query(_gcn_query(n))
+    h = db.query(gcn_square_loss(n))
     h.step(wrt=("Edge", "Node"))
     assert h.last.plan.owner_aligned
     cert = certify(h.last, env)
@@ -545,7 +529,7 @@ def test_wave_certifier_rejects_tampered_plans():
     total = _rel_bytes(db0.get("Edge")) + _rel_bytes(db0.get("Node"))
     db2 = _gcn_fill(repro.Database(memory_budget=total / 3), n=n)
     env2 = {"Edge": db2.get("Edge"), "Node": db2.get("Node")}
-    h2 = db2.query(_gcn_query(n))
+    h2 = db2.query(gcn_square_loss(n))
     h2.step(wrt=("Edge", "Node"))
     plan2 = h2.last.plan
     owners = np.asarray(env2["Edge"].keys)[:, env2["Edge"].owner_dim]

@@ -124,6 +124,7 @@ _SPMD_SCRIPT = textwrap.dedent(
     from repro.core.keys import L, R, eq_pred, jproj, project_key
     from repro.core.planner import input_pspecs, plan_query
     from repro.core.relation import DenseRelation
+    from repro.launch.mesh import auto_mesh
 
     join = fra.Join(eq_pred((1, 0)), jproj(L(0), L(1), R(1)), MATMUL,
                     fra.scan("A", 2), fra.scan("B", 2))
@@ -139,7 +140,7 @@ _SPMD_SCRIPT = textwrap.dedent(
     (plan,) = plans.values()
     assert plan.kind == "copartition", plan.kind
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = auto_mesh((8,), ("model",))
     specs = input_pspecs(q, plans)
     a_sh = jax.device_put(a, NamedSharding(mesh, specs["A"]))
     b_sh = jax.device_put(b, NamedSharding(mesh, specs["B"]))
@@ -151,7 +152,6 @@ _SPMD_SCRIPT = textwrap.dedent(
         ).data
 
     # NamedShardings carry the mesh; no global mesh context needed
-    # (jax.set_mesh does not exist on this jax version).
     out = run(a_sh, b_sh)
     hlo = jax.jit(run).lower(a_sh, b_sh).compile().as_text()
 

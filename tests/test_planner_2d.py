@@ -231,6 +231,17 @@ def test_resolve_mesh_specs(monkeypatch):
         resolve_mesh("nope")
 
 
+def test_host_meshes_have_auto_axes(monkeypatch):
+    # Explicit axes (jax.make_mesh's default) would make the co-partition
+    # plan's sharded contraction a ShardingTypeError
+    from jax.sharding import AxisType
+
+    real = jax.devices()
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: real[:1])
+    assert set(make_host_mesh().axis_types) == {AxisType.Auto}
+    assert set(resolve_mesh("host").axis_types) == {AxisType.Auto}
+
+
 # ---------------------------------------------------------------------------
 # SPMD: the 4×2 host mesh (tier1-spmd lane, 8 virtual CPU devices)
 # ---------------------------------------------------------------------------
