@@ -1,0 +1,7 @@
+"""``step.mfu`` in the cells whose step time is ``minibatch_step_ms``."""
+
+from perfbench.metrics_common import read_as
+
+LAYER = "model step"
+MOVES = "minibatch_step_ms"
+read = read_as("step.mfu")

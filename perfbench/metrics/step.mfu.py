@@ -1,0 +1,14 @@
+"""The whole step's share of the chip's bf16 peak: the operations the
+forward and backward passes require, counted from the shapes (nothing
+recomputed counts), times the steps of the traced window, over the
+window and the peak, in %."""
+
+LAYER = "model step"
+MOVES = "step_ms"
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.peaks is None:
+        return None
+    flops = ctx.work["flops"] * ctx.steps
+    return 100.0 * flops / (ctx.trace.window_s * ctx.peaks["bf16_flops_per_s"])

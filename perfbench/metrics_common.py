@@ -1,0 +1,34 @@
+"""Arithmetic shared by the metric readers (``metrics/``)."""
+
+from perfbench import harness, trace_reduce
+
+
+def read_as(metric: str):
+    """The ``read`` of ``metrics/<metric>.py``: a metric split by the
+    end-to-end metric it moves reads what the one it splits reads."""
+    return harness._module(harness.BENCH / "metrics" / f"{metric}.py",
+                           f"perfbench_metric_{metric}").read
+
+
+def roofline_s(calls, peaks: dict) -> float:
+    """Least seconds for ``(flops, bytes)`` calls on a chip with these
+    peaks: per call the larger of operations over the bf16 peak and
+    bytes over the HBM peak."""
+    return sum(max(f / peaks["bf16_flops_per_s"], b / peaks["hbm_bytes_per_s"])
+               for f, b in calls)
+
+
+def roofline_share(ctx, kernel: str, pattern: str):
+    """% of its roofline the kernel reached over the traced window: the
+    least time of the calls the model says a step makes through it, over
+    the device time of the kernel's events. None (nothing to read) where
+    the trace does not hold exactly those calls, one event each per
+    step: the kernel is then off the path, or the calls it makes are not
+    the ones whose work is counted."""
+    calls = ctx.work["kernels"].get(kernel)
+    if ctx.trace is None or ctx.peaks is None or not calls:
+        return None
+    if trace_reduce.kernel_calls(ctx.trace, pattern) != ctx.steps * len(calls):
+        return None
+    spent = trace_reduce.kernel_s(ctx.trace, pattern)
+    return 100.0 * ctx.steps * roofline_s(calls, ctx.peaks) / spent
