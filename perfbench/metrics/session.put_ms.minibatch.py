@@ -1,0 +1,7 @@
+"""``session.put_ms`` in the cells whose step time is ``minibatch_step_ms``."""
+
+from perfbench.metrics_common import read_as
+
+LAYER = "session front door"
+MOVES = "minibatch_step_ms"
+read = read_as("session.put_ms")

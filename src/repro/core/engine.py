@@ -59,6 +59,14 @@ from . import fra, kernels, planner
 from . import rewrite as _rewrite
 from .autodiff import GradientProgram
 from .relation import CooRelation, DenseRelation, pad_coo_nnz
+from .spans import (
+    ENGINE_CALL,
+    ENGINE_DISPATCH,
+    ENGINE_LOWER,
+    ENGINE_PLAN,
+    span,
+    spanned,
+)
 
 AnyRel = Union[DenseRelation, CooRelation]
 Env = Dict[str, AnyRel]
@@ -389,6 +397,7 @@ class Compiled:
 
         return jax.tree_util.tree_map(cut, out, self.lowered.out_shape)
 
+    @spanned(ENGINE_CALL)
     def __call__(self, env: Env, seed: Optional[AnyRel] = None):
         sig = env_signature(env, seed)
         if sig != self.lowered.sig:
@@ -437,7 +446,8 @@ class Compiled:
                     warnings.warn(ReshardWarning(name, nbytes), stacklevel=2)
             donated = jax.device_put(donated, sh_don)
             kept = jax.device_put(kept, sh_kept)
-        out = self._jitted(donated, kept, seed)
+        with span(ENGINE_DISPATCH):
+            out = self._jitted(donated, kept, seed)
         return self._unpad(out) if self.pad_nnz else out
 
     def lower_text(self, *, compiled: bool = True) -> str:
@@ -633,96 +643,105 @@ class Lowered:
             self._compiled.move_to_end(key)
             return hit
 
-        # --- plan: the distribution planner picks a JoinPlan per join ----
-        # (planner._rel_bytes reads sizes off relations whose payloads are
-        # ShapeDtypeStructs, so the abstract env is a valid stats source)
-        fwd_query = (
-            self.program.forward
-            if isinstance(self.program, GradientProgram)
-            else self.program
-        )
-        plans = planner.plan_query(
-            fwd_query,
-            self.abstract_env,
-            n_devices,
-            mem_budget=mem_budget,
-            geometry=geo,
-            committed=committed,
-            stats=stats,
-        )
-        input_specs = planner.input_pspecs(fwd_query, plans)
+        with span(ENGINE_PLAN):
+            # --- plan: the distribution planner picks a JoinPlan per join ----
+            # (planner._rel_bytes reads sizes off relations whose payloads are
+            # ShapeDtypeStructs, so the abstract env is a valid stats source)
+            fwd_query = (
+                self.program.forward
+                if isinstance(self.program, GradientProgram)
+                else self.program
+            )
+            plans = planner.plan_query(
+                fwd_query,
+                self.abstract_env,
+                n_devices,
+                mem_budget=mem_budget,
+                geometry=geo,
+                committed=committed,
+                stats=stats,
+            )
+            input_specs = planner.input_pspecs(fwd_query, plans)
 
-        # --- rechunk stage: relations whose committed layout is not the -
-        # plan's grid get an explicit, costed re-blocking (the all-to-all
-        # the bytes-moved model already charged via committed=): record
-        # them so __call__ books the move as planned, not silent
-        rechunks: Dict[str, int] = {}
-        if committed and mesh is not None:
-            for name, spec in committed.items():
-                planned = input_specs.get(name)
-                if _norm_spec(spec) != _norm_spec(planned):
-                    rel = self.abstract_env.get(name)
-                    rechunks[name] = (
-                        int(planner._rel_bytes(rel)) if rel is not None else 0
+            # --- rechunk stage: relations whose committed layout is not the -
+            # plan's grid get an explicit, costed re-blocking (the all-to-all
+            # the bytes-moved model already charged via committed=): record
+            # them so __call__ books the move as planned, not silent
+            rechunks: Dict[str, int] = {}
+            if committed and mesh is not None:
+                for name, spec in committed.items():
+                    planned = input_specs.get(name)
+                    if _norm_spec(spec) != _norm_spec(planned):
+                        rel = self.abstract_env.get(name)
+                        rechunks[name] = (
+                            int(planner._rel_bytes(rel))
+                            if rel is not None
+                            else 0
+                        )
+
+            # --- jit: plans become in_shardings, XLA inserts the collectives -
+            engine = self.engine
+            table = self.dispatch
+            program = self.program
+
+            jit_kwargs: Dict[str, Any] = (
+                {"donate_argnums": (0,)} if donate else {}
+            )
+            shardings = None
+            pad_nnz: Dict[str, int] = {}
+            coo_pins: Dict[str, CooRelation] = {}
+            if mesh is not None:
+                sh_don: Dict[str, AnyRel] = {}
+                sh_kept: Dict[str, AnyRel] = {}
+                for k, rel in self.abstract_env.items():
+                    sharding, pad = self._rel_sharding(
+                        k, rel, input_specs.get(k), mesh
                     )
+                    (sh_don if k in donate else sh_kept)[k] = sharding
+                    if pad is not None:
+                        pad_nnz[k] = pad
+                    if isinstance(sharding, CooRelation) and tuple(
+                        sharding.values.spec
+                    ):
+                        # nnz-sharded COO: pin the layout inside the jitted
+                        # step too, so the traced segment-sum + scatter-add
+                        # stays partitioned over the planned data axes (the
+                        # per-shard local segsum + psum the plan costed)
+                        # regardless of how XLA would re-place the operands.
+                        coo_pins[k] = sharding
+                jit_kwargs["in_shardings"] = (sh_don, sh_kept, None)
+                shardings = (sh_don, sh_kept)
 
-        # --- jit: plans become in_shardings, XLA inserts the collectives -
-        engine = self.engine
-        table = self.dispatch
-        program = self.program
-
-        jit_kwargs: Dict[str, Any] = {"donate_argnums": (0,)} if donate else {}
-        shardings = None
-        pad_nnz: Dict[str, int] = {}
-        coo_pins: Dict[str, CooRelation] = {}
-        if mesh is not None:
-            sh_don: Dict[str, AnyRel] = {}
-            sh_kept: Dict[str, AnyRel] = {}
-            for k, rel in self.abstract_env.items():
-                sharding, pad = self._rel_sharding(
-                    k, rel, input_specs.get(k), mesh
+            def step(donated_env: Env, kept_env: Env, seed):
+                env = dict(kept_env)
+                env.update(donated_env)
+                for name, sh in coo_pins.items():
+                    rel = env[name]
+                    env[name] = CooRelation(
+                        jax.lax.with_sharding_constraint(rel.keys, sh.keys),
+                        jax.lax.with_sharding_constraint(
+                            rel.values, sh.values
+                        ),
+                        rel.extents,
+                        rel.owner_dim,
+                        rel.shard_offsets,
+                    )
+                return engine._execute(
+                    env, seed, dispatch=table, program=program
                 )
-                (sh_don if k in donate else sh_kept)[k] = sharding
-                if pad is not None:
-                    pad_nnz[k] = pad
-                if isinstance(sharding, CooRelation) and tuple(
-                    sharding.values.spec
-                ):
-                    # nnz-sharded COO: pin the layout inside the jitted
-                    # step too, so the traced segment-sum + scatter-add
-                    # stays partitioned over the planned data axes (the
-                    # per-shard local segsum + psum the plan costed)
-                    # regardless of how XLA would re-place the operands.
-                    coo_pins[k] = sharding
-            jit_kwargs["in_shardings"] = (sh_don, sh_kept, None)
-            shardings = (sh_don, sh_kept)
 
-        def step(donated_env: Env, kept_env: Env, seed):
-            env = dict(kept_env)
-            env.update(donated_env)
-            for name, sh in coo_pins.items():
-                rel = env[name]
-                env[name] = CooRelation(
-                    jax.lax.with_sharding_constraint(rel.keys, sh.keys),
-                    jax.lax.with_sharding_constraint(rel.values, sh.values),
-                    rel.extents,
-                    rel.owner_dim,
-                    rel.shard_offsets,
-                )
-            return engine._execute(env, seed, dispatch=table, program=program)
-
-        compiled = Compiled(
-            self,
-            jax.jit(step, **jit_kwargs),
-            donate,
-            plans,
-            input_specs,
-            mesh,
-            geo,
-            shardings,
-            pad_nnz,
-            rechunks,
-        )
+            compiled = Compiled(
+                self,
+                jax.jit(step, **jit_kwargs),
+                donate,
+                plans,
+                input_specs,
+                mesh,
+                geo,
+                shardings,
+                pad_nnz,
+                rechunks,
+            )
         self._compiled[key] = compiled
         while len(self._compiled) > _MAX_COMPILED:
             self._compiled.popitem(last=False)
@@ -1200,54 +1219,55 @@ class RAEngine:
         hit = self._lowered.get(key)
         if hit is not None:
             return hit
-        # mandatory validate stage (repro.analysis.typecheck): schema/
-        # shape/dtype-check the forward graph at this env's shapes before
-        # the rewrite/plan/jit stages run — a malformed query fails here
-        # with node-path diagnostics instead of a trace-time error from
-        # deep inside the chunked lowering. Raises ValidationError on
-        # error-severity findings; the full report (warnings included)
-        # rides on the returned Lowered as ``check_report``.
-        from ..analysis.typecheck import ValidationError, check_query
+        with span(ENGINE_LOWER):
+            # mandatory validate stage (repro.analysis.typecheck): schema/
+            # shape/dtype-check the forward graph at this env's shapes before
+            # the rewrite/plan/jit stages run — a malformed query fails here
+            # with node-path diagnostics instead of a trace-time error from
+            # deep inside the chunked lowering. Raises ValidationError on
+            # error-severity findings; the full report (warnings included)
+            # rides on the returned Lowered as ``check_report``.
+            from ..analysis.typecheck import ValidationError, check_query
 
-        check_report = check_query(
-            self.forward_query, env, fuse_join_agg=self.fuse_join_agg
-        )
-        if not check_report.ok:
-            raise ValidationError(check_report)
-        abstract_env = {k: _abstract(v) for k, v in env.items()}
-        abstract_seed = None if seed is None else _abstract(seed)
-        program = None
-        report = None
-        if rules is not None:
-            program, report = _rewrite.rewrite_program(
-                self.program, abstract_env, stats=stats, rules=rules
+            check_report = check_query(
+                self.forward_query, env, fuse_join_agg=self.fuse_join_agg
             )
-        # a ResolutionLog (not a plain dict) so each dispatch decision
-        # carries its site-info snapshot — analysis.kernelcheck replays
-        # resolve_impl on the snapshots to certify the decisions stable
-        resolutions: Dict[str, str] = kernels.ResolutionLog()
-        out_shape = jax.eval_shape(
-            functools.partial(
-                self._execute,
-                dispatch=table,
-                resolutions=resolutions,
+            if not check_report.ok:
+                raise ValidationError(check_report)
+            abstract_env = {k: _abstract(v) for k, v in env.items()}
+            abstract_seed = None if seed is None else _abstract(seed)
+            program = None
+            report = None
+            if rules is not None:
+                program, report = _rewrite.rewrite_program(
+                    self.program, abstract_env, stats=stats, rules=rules
+                )
+            # a ResolutionLog (not a plain dict) so each dispatch decision
+            # carries its site-info snapshot — analysis.kernelcheck replays
+            # resolve_impl on the snapshots to certify the decisions stable
+            resolutions: Dict[str, str] = kernels.ResolutionLog()
+            out_shape = jax.eval_shape(
+                functools.partial(
+                    self._execute,
+                    dispatch=table,
+                    resolutions=resolutions,
+                    program=program,
+                ),
+                abstract_env,
+                abstract_seed,
+            )
+            low = Lowered(
+                self,
+                sig,
+                table,
+                abstract_env,
+                abstract_seed,
+                out_shape,
+                resolutions,
                 program=program,
-            ),
-            abstract_env,
-            abstract_seed,
-        )
-        low = Lowered(
-            self,
-            sig,
-            table,
-            abstract_env,
-            abstract_seed,
-            out_shape,
-            resolutions,
-            program=program,
-            rewrite_report=report,
-            check_report=check_report,
-        )
+                rewrite_report=report,
+                check_report=check_report,
+            )
         self._lowered[key] = low
         return low
 

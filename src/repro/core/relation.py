@@ -213,11 +213,17 @@ def measure_stats(rel):
     refreshes on ``put``.
 
     DenseRelation key sets are full grids, so every statistic is exact
-    and free (distinct = extents, density = 1, and each histogram bucket
-    holds its share of the uniform grid). CooRelation key columns are
-    counted with ``np.unique`` / ``np.histogram`` over the live
-    (non-padded) rows — a host-side pass over concrete key arrays, i.e.
-    a data-loading step like ``owner_partition``, never a traced one."""
+    (distinct = extents, density = 1, and each histogram bucket holds
+    its share of the uniform grid), but not free: the histograms are
+    taken on the host with ``np.histogram`` over each key range. On the
+    host of a TPU v5e, refreshing an 8,192 × 2,000 batch, its 8,192
+    labels and a 2,000-vector took 0.80 ms together, 40 % of a 2.0 ms
+    logistic-regression step (the ``repro/session.stats`` span;
+    ``put(..., refresh_stats=False)`` skips the pass when only values
+    changed). CooRelation key columns are counted with ``np.unique`` /
+    ``np.histogram`` over the live (non-padded) rows — a host-side pass
+    over concrete key arrays, i.e. a data-loading step like
+    ``owner_partition``, never a traced one."""
     from .planner import HIST_BUCKETS, RelationStats
 
     def column_hist(values, extent, per_value=1):

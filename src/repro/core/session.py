@@ -61,6 +61,14 @@ from . import rewrite as _rewrite
 from . import sql as _sql
 from .autodiff import GradientProgram, ra_autodiff
 from .relation import CooRelation, DenseRelation, measure_stats
+from .spans import (
+    ENGINE_LOOKUP,
+    SESSION_PUT,
+    SESSION_STATS,
+    SESSION_STEP,
+    span,
+    spanned,
+)
 
 AnyRel = Union[DenseRelation, CooRelation]
 
@@ -167,7 +175,8 @@ class Catalog:
                 f"{key_attrs} for key arity {relation.key_arity}"
             )
         if refresh_stats or prev is None:
-            stats = measure_stats(relation)
+            with span(SESSION_STATS):
+                stats = measure_stats(relation)
         else:
             stats = prev.stats
         entry = TableEntry(name, relation, key_attrs, stats)
@@ -372,6 +381,7 @@ class Database:
 
     # -- catalog front door ------------------------------------------------
 
+    @spanned(SESSION_PUT)
     def put(
         self,
         name: str,
@@ -729,6 +739,7 @@ class Database:
 
     # -- staged execution (the engine underneath) --------------------------
 
+    @spanned(ENGINE_LOOKUP)
     def _compiled_for(
         self,
         program,
@@ -983,6 +994,7 @@ class QueryHandle:
             return seed
         return DenseRelation(jnp.asarray(seed), self.query.root.key_arity)
 
+    @spanned(SESSION_STEP)
     def _run_grad(
         self,
         wrt: Optional[Sequence[str]],

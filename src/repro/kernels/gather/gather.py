@@ -61,6 +61,7 @@ def gather_rows_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((chunk, 1, d), table.dtype),
         interpret=interpret,
+        name="gather_join",
     )
     table3 = table.reshape(n, 1, d)
     out = jax.lax.map(lambda r: call(r, table3), rows.reshape(-1, chunk))

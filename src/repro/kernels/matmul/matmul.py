@@ -64,4 +64,5 @@ def matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="blocked_matmul",
     )(x, y)

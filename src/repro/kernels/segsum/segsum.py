@@ -83,4 +83,5 @@ def segment_sum_pallas(
         out_shape=jax.ShapeDtypeStruct((num_segments, d), msg.dtype),
         scratch_shapes=[pltpu.VMEM((bs, bd), jnp.float32)],
         interpret=interpret,
+        name="segment_sum",
     )(seg, msg)

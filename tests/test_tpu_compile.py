@@ -5,6 +5,9 @@ that is described, not attached, so a block layout or memory use the chip
 refuses fails here, at the real widths, without a chip. Nothing runs: the
 compiled text must hold the kernel (``tpu_custom_call``).
 
+The kernels carry their names (``pallas_call(name=...)``) into the
+compiled text, where a profiler trace reports them.
+
 Widths: the GCN's Σ and gather at ogbn-arxiv shape (169,343 nodes,
 1,166,243 edges plus a self loop per node; feature widths 128 and 256),
 the logistic-regression matmul at 524,288 × 1,024 f32, a 4096² bf16
@@ -18,6 +21,7 @@ pytest-xdist worker imports this file would fail the other workers.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -121,3 +125,33 @@ def test_ssm_scan_compiles(one_chip):
         (shape, jnp.float32),
     )
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize(
+    "name, kernel, shapes",
+    [
+        (
+            "segment_sum",
+            lambda msg, seg: segment_sum(msg, seg, 1_024, interpret=False),
+            (((70_000, 128), jnp.float32), ((70_000,), jnp.int32)),
+        ),
+        (
+            "gather_join",  # launches inside a lax.map: named all the same
+            lambda table, rows: gather_rows(table, rows, interpret=False),
+            (((1_024, 128), jnp.float32), ((140_000,), jnp.int32)),
+        ),
+        (
+            "blocked_matmul",
+            lambda x, y: blocked_matmul(x, y, interpret=False),
+            (((1_024, 256), jnp.float32), ((256, 1), jnp.float32)),
+        ),
+    ],
+    ids=["segment_sum", "gather_join", "blocked_matmul"],
+)
+def test_kernel_keeps_its_name_in_the_compiled_text(one_chip, name, kernel, shapes):
+    text = _compile(kernel, one_chip, *shapes).as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert calls
+    for ln in calls:
+        assert re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", ln), ln
