@@ -525,11 +525,19 @@ class AccumModel:
     """A VMEM scratch accumulator carried across the ``axis`` grid
     dimension: zeroed when the axis coordinate equals ``init_at``, with
     the output block stored at the axis' last step (``store="last"``) or
-    at every step (``store="every"``, the scan kernels)."""
+    at every step (``store="every"``, the scan kernels).
+
+    ``store="run"`` is the form of a data-dependent schedule (the Σ's
+    visit grid): the accumulator is zeroed at the first program of each
+    run of equal output-block indices, in the grid's sequential order,
+    and stored at its last, so a block is stored once per run. Where the
+    output index is only an Interval statically, the schedule orders the
+    blocks (a run each), and the simulation proves the bounds alone; a
+    concrete schedule's runs are counted like any stores."""
 
     axis: int
     init_at: int = 0
-    store: str = "last"  # "last" | "every"
+    store: str = "last"  # "last" | "every" | "run"
 
 
 @dataclass(frozen=True)
@@ -656,6 +664,20 @@ def _map_axis_deps(index_map: Callable, grid: Tuple[int, ...]) -> Tuple[int, ...
     return tuple(deps)
 
 
+def _next_coord(
+    coord: Tuple[int, ...], grid: Tuple[int, ...]
+) -> Optional[Tuple[int, ...]]:
+    """The program after ``coord`` in the grid's sequential (row-major,
+    last axis fastest) order; None after the last."""
+    nxt = list(coord)
+    for ax in reversed(range(len(grid))):
+        if nxt[ax] + 1 < grid[ax]:
+            nxt[ax] += 1
+            return tuple(nxt)
+        nxt[ax] = 0
+    return None
+
+
 def _coord_range(v: Coord) -> Tuple[int, int]:
     if isinstance(v, Interval):
         return v.lo, v.hi
@@ -708,6 +730,7 @@ def simulate_grid(
             acc = None
 
     oob_seen = set()
+    ordered = False
     stores: Dict[Tuple[int, ...], int] = {}
     out_counts = model.output.block_counts()
     for coord in coords:
@@ -737,6 +760,13 @@ def simulate_grid(
                         ))
         if acc is None or acc.store == "every":
             stored = True
+        elif acc.store == "run":
+            oidx = model.output.index_map(*coord)
+            if any(isinstance(v, Interval) for v in oidx):
+                ordered = True  # runs ordered by the schedule, not countable
+                continue
+            nxt = _next_coord(coord, grid)
+            stored = nxt is None or model.output.index_map(*nxt) != oidx
         else:
             stored = coord[acc.axis] == grid[acc.axis] - 1
         if stored:
@@ -758,7 +788,7 @@ def simulate_grid(
             f"{len(races)} output block(s) stored by more than one program "
             f"instance, e.g. block {races[0]} stored {stores[races[0]]}x",
         ))
-    if exhaustive:
+    if exhaustive and not ordered:
         import itertools
 
         missing = [
@@ -922,6 +952,8 @@ def _sanitize_site(op: str, info: Dict, **concrete: Any) -> None:
 
 
 def _segsum_sanitizer(msg: jnp.ndarray, seg: jnp.ndarray, num_segments: int) -> jnp.ndarray:
+    import numpy as np
+
     from repro.kernels.segsum.ref import segment_sum_ref
 
     if _is_concrete(msg, seg):
@@ -929,7 +961,8 @@ def _segsum_sanitizer(msg: jnp.ndarray, seg: jnp.ndarray, num_segments: int) -> 
             "nnz": msg.shape[0], "dim": msg.shape[1],
             "num_segments": num_segments, "dtype": msg.dtype,
         }
-        _sanitize_site("segment_sum", info)
+        # concrete ids give the exact visit schedule the kernel would run
+        _sanitize_site("segment_sum", info, seg=np.asarray(seg))
     return segment_sum_ref(msg, seg, num_segments)
 
 

@@ -142,6 +142,37 @@ def test_segment_sum_interpret_matches_ref_fwd_and_grad():
     )
 
 
+def test_segment_sum_interpret_tier_sorts_unsorted_padded_ids_fwd_and_grad():
+    """The interpret tier as the registry resolves it, on COO-like ids:
+    unsorted, a hub over several edge blocks, -1 padding and ids out of
+    range, E and S not multiples of the kernel's tiles."""
+    rng = np.random.default_rng(2)
+    e, d, s = 1400, 12, 300
+    seg = rng.integers(0, s, size=e)
+    seg[rng.random(e) < 0.5] = 17
+    seg[rng.random(e) < 0.1] = -1
+    seg[rng.random(e) < 0.05] = s + 3
+    seg = jnp.asarray(seg, jnp.int32)
+    msg = jnp.asarray(rng.normal(size=(e, d)), jnp.float32)
+    fn = K.resolve_impl(
+        "segment_sum", {"dtype": jnp.float32},
+        K.make_table(("interpret",), backend="cpu"),
+    ).fn
+    ref = jax.ops.segment_sum(msg, seg, num_segments=s)
+    np.testing.assert_allclose(
+        np.asarray(fn(msg, seg, s)), np.asarray(ref), rtol=1e-5, atol=1e-4
+    )
+    w = jnp.asarray(rng.normal(size=(s, d)), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(jax.grad(lambda m: jnp.sum(fn(m, seg, s) * w))(msg)),
+        np.asarray(jax.grad(
+            lambda m: jnp.sum(jax.ops.segment_sum(m, seg, num_segments=s) * w)
+        )(msg)),
+        rtol=1e-6,
+        atol=1e-6,
+    )
+
+
 def test_blocked_matmul_interpret_matches_ref_fwd_and_grad():
     from repro.kernels.matmul.ops import blocked_matmul
     from repro.kernels.matmul.ref import matmul_ref

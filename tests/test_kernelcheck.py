@@ -280,6 +280,64 @@ def test_sanitizer_agrees_with_static_verdict(monkeypatch):
     assert exc.value.kind == "dtype-domain"
 
 
+@pytest.mark.parametrize(
+    "info", kernelcheck.default_shape_classes("segment_sum")[:3],
+    ids=["tile-exact", "ragged", "one-tile"],
+)
+def test_segment_sum_contract_is_a_visit_grid_that_certifies(info):
+    """The Σ's contract describes its visit grid: one program per visit,
+    E'/512 + S'/128 of them, a run accumulator, and tiles and blocks in
+    range statically; the certifier finds nothing at the shape classes."""
+    model = K.kernel_contract("segment_sum").grid_model(dict(info))
+    e, s = info["nnz"], info["num_segments"]
+    assert model.grid == (-(-e // 512) + -(-s // 128),)
+    assert model.accumulator == AccumModel(axis=0, store="run")
+    out = model.output.index_map(0)
+    assert isinstance(out[0], K.Interval) and out[0].hi == -(-s // 128) - 1
+    assert K.simulate_grid(model) == []
+
+
+def test_segment_sum_sanitizer_replays_the_concrete_schedule():
+    """Given the ids, the contract's grid is the schedule the kernel runs:
+    exact tile and block indices, one store per tile, every tile stored."""
+    rng = np.random.default_rng(5)
+    e, s = 1800, 400
+    ids = rng.integers(-1, s + 5, e)
+    ids[rng.random(e) < 0.5] = 3  # a hub over several edge blocks
+    info = {"nnz": e, "dim": 8, "num_segments": s, "dtype": F32}
+    model = K.kernel_contract("segment_sum").grid_model(info, seg=ids)
+    tiles = [model.output.index_map(v)[0] for v in range(model.grid[0])]
+    assert all(isinstance(t, int) for t in tiles)
+    assert tiles == sorted(tiles) and set(tiles) == set(range(4))
+    blocks = [model.inputs[1].index_map(v)[0] for v in range(model.grid[0])]
+    assert all(isinstance(b, int) and 0 <= b < 4 for b in blocks)
+    assert K.simulate_grid(model) == []
+
+
+def _run_model(tiles, num_tiles):
+    return GridModel(
+        grid=(len(tiles),),
+        inputs=(BlockModel("msg", (512, 8), (512, 8), lambda v: (0, 0)),),
+        output=BlockModel(
+            "out", (128 * num_tiles, 8), (128, 8), lambda v: (tiles[v], 0)
+        ),
+        accumulator=AccumModel(axis=0, store="run"),
+    )
+
+
+@pytest.mark.parametrize(
+    "tiles,num_tiles,code",
+    [([0, 0, 1, 0], 2, "grid-race"), ([0, 2, 2], 3, "grid-uncovered")],
+    ids=["tile-revisited", "tile-skipped"],
+)
+def test_run_accumulator_rejects_a_broken_schedule(tiles, num_tiles, code):
+    """A schedule that leaves a tile and comes back stores it twice; one
+    that skips a tile never stores it."""
+    kinds = [kind for kind, _ in K.simulate_grid(_run_model(tiles, num_tiles))]
+    assert kinds == [code]
+    assert K.simulate_grid(_run_model([0, 0, 1, 2, 2], 3)) == []
+
+
 def test_sanitizer_clean_sites_match_ref_oracle():
     from repro.kernels.gather.ref import gather_rows_ref
     from repro.kernels.segsum.ref import segment_sum_ref

@@ -86,6 +86,13 @@ def test_segment_sum_compiles_at_arxiv_width(one_chip, dim):
         ((ARXIV_EDGES,), jnp.int32),
     )
     _assert_kernel(compiled)
+    # one launch per call, whose trace event the roofline reads
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    # the messages in segment order are the one E' × D copy the call
+    # holds (a second, such as a take and then a pad, would double it)
+    padded = (ARXIV_EDGES + (-ARXIV_EDGES) % 512) * dim * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= padded + 64 * 2**20, (temp, padded)
 
 
 @pytest.mark.parametrize("dim", [128, 256])
