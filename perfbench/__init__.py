@@ -11,8 +11,16 @@ cell or metric is a file found by its name:
   from the shapes) and ``reference/<model>.py`` (the plain JAX
   reference, which imports nothing of the program);
 * ``traffic/<mix>.json``: the parameters ``feed.Feed`` reads;
+* ``tiny/configs/<config>.json``, ``tiny/traffic/<mix>.json``: the keys
+  that the CPU tests (``tests/perfbench``) change so that a cell runs at
+  a size they hold, laid over the file of the same name; every
+  configuration and mix that a cell uses has one, ``{}`` where the file
+  runs as it is;
 * ``cells/<cell>.json``: the limits of the comparison that decides
   ``correct`` (``check.py``);
 * ``metrics/<metric>.py``: one reader per metric of ``BENCHMARK.json``;
 * ``peaks.json``: the device peaks, keyed by ``device_kind``.
+
+``models/__init__.py`` gives the contract of a model's module, and the
+files a new configuration brings, on one chip or four.
 """

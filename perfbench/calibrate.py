@@ -29,18 +29,20 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def readings(workload: str, seeds, root=ROOT, out=None) -> list:
+    import jax
     import jax.numpy as jnp
 
     from perfbench import check, harness
     from perfbench.feed import Feed
 
     cell = harness.load_cell(root, workload, trace=False)
+    devices = jax.devices()[:cell.chips]
     rows = []
     for seed in seeds:
         feed = Feed(cell.traffic, cell.model.rows(cell.cfg), seed)
-        inputs = cell.model.make_inputs(cell.cfg, feed, seed)
+        inputs = cell.model.make_inputs(cell.cfg, feed, seed, devices)
         trainer, prog = harness.first_steps(cell, feed, inputs,
-                                            harness.Spans(False))
+                                            harness.Spans(False), devices)
         del trainer
         gc.collect()
         ref = cell.reference.run(cell.cfg, inputs)

@@ -3,15 +3,17 @@ decides ``correct``, and the result line.
 
 Set-up makes the cell's data and initial parameters from the seed,
 builds the program's training step with its state (``models/<model>``)
-and drives it through the first ``check.STEPS`` steps with the window's
-own call and feed, keeping their readings. That compiles, or loads from
-the compile cache, every program the window runs. The window then
+on the cell's chips, the first ``chips`` devices JAX lists, and drives
+it through the first ``check.STEPS`` steps with the window's own call
+and feed, keeping their readings. That compiles, or loads from the
+compile cache, every program the window runs. The window then
 dispatches step i, waits for step i-1, and stops dispatching once
 ``seconds`` have passed; it closes when its last step completes. After
-it the device's memory peak is read (the live arrays' peak plus the
-largest temporaries of the programs the window runs), the program's
-state is freed, and the plain reference (``reference/<model>``) follows
-the same first steps from the same parameters on the same rows.
+it the memory peak of the fullest of the cell's chips is read (the live
+arrays' peak plus the largest temporaries of the programs the window
+runs), the program's state is freed, and the plain reference
+(``reference/<model>``) follows the same first steps from the same
+parameters on the same rows.
 
 ``--trace 1`` runs the same window under the profiler, with the
 benchmark's spans around its calls into the program, and reports the
@@ -178,9 +180,13 @@ class CompileCounter:
 @dataclasses.dataclass
 class Context:
     """What a metric reader reads (``metrics/<name>.py``: ``read(ctx)``
-    returns the value, or None when it finds nothing to read)."""
+    returns the value, or None when it finds nothing to read). ``chips``
+    is the number of chips the cell ran on; ``work`` is
+    ``models/<model>.work``: the whole step's ``flops``, and the kernel
+    calls that each chip makes."""
 
     cell: Cell
+    chips: int
     work: dict
     steps: int
     window_s: float
@@ -214,11 +220,12 @@ def _finite(x):
     return x if isinstance(x, (int, float)) and math.isfinite(x) else None
 
 
-def first_steps(cell: Cell, feed: Feed, inputs: dict, spans: Spans):
-    """Build the program's step with its state and drive it through the
-    first ``check.STEPS`` steps with the window's own call and feed.
-    Returns the trainer, to be handed on, and the program's readings."""
-    trainer = cell.model.Trainer(cell.cfg, feed, inputs, spans)
+def first_steps(cell: Cell, feed: Feed, inputs: dict, spans: Spans, devices):
+    """Build the program's step with its state on ``devices`` and drive
+    it through the first ``check.STEPS`` steps with the window's own call
+    and feed. Returns the trainer, to be handed on, and the program's
+    readings."""
+    trainer = cell.model.Trainer(cell.cfg, feed, inputs, spans, devices)
     states, losses = [trainer.state()], []
     for i in range(check.STEPS):
         out = jax.block_until_ready(trainer.step(i))
@@ -232,8 +239,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         root: pathlib.Path = ROOT, t_start: Optional[float] = None,
         check_device: bool = True, out=None, err=None) -> int:
     """One run; prints the result line and returns the exit code.
-    ``check_device=False`` skips the look for a chip and the peak table
-    (for tests on the CPU)."""
+    ``check_device=False`` skips the look for an accelerator and the peak
+    table (for tests on the CPU); JAX has to list as many devices as the
+    cell has chips either way."""
     out = out or sys.stdout
     err = err or sys.stderr
     t_start = time.perf_counter() if t_start is None else t_start
@@ -241,27 +249,27 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         cell = load_cell(root, workload, trace)
         devices = jax.devices()
         dev = devices[0]
-        peaks = None
-        if check_device:
-            if dev.platform == "cpu":
-                raise SetupError("needs an accelerator; JAX found only the CPU")
-            if len(devices) < cell.chips:
-                raise SetupError(f"{workload} needs {cell.chips} chips; JAX "
-                                 f"sees {len(devices)}")
-            peaks = peaks_for(dev.device_kind)
+        if check_device and dev.platform == "cpu":
+            raise SetupError("needs an accelerator; JAX found only the CPU")
+        if len(devices) < cell.chips:
+            raise SetupError(f"{workload} needs {cell.chips} chips; JAX "
+                             f"sees {len(devices)}")
+        peaks = peaks_for(dev.device_kind) if check_device else None
     except SetupError as e:
         print(f"perfbench: {e}", file=err)
         return 2
+    # the chips the cell runs on: the model is given these, and the
+    # memory peak is read on these
     used = devices[:cell.chips]
 
     spans = Spans(bool(trace))
     model = cell.model
     feed = Feed(cell.traffic, model.rows(cell.cfg), seed)
-    inputs = model.make_inputs(cell.cfg, feed, seed)
+    inputs = model.make_inputs(cell.cfg, feed, seed, used)
     client = dev.client
     known = client.live_executables()  # held, so that no id is reused
     seen = {id(e) for e in known}
-    trainer, prog = first_steps(cell, feed, inputs, spans)
+    trainer, prog = first_steps(cell, feed, inputs, spans, used)
     # the programs the first steps compiled or loaded: those the window runs
     programs = [e for e in client.live_executables() if id(e) not in seen]
     del known, seen
@@ -308,8 +316,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     ref = cell.reference.run(cell.cfg, inputs)
     correct, checks = check.judge(check.compare(prog, ref), cell.limits)
 
-    ctx = Context(cell, model.work(cell.cfg, feed), steps, window_s, setup_s,
-                  memory_peak, compiles.count, reduced, peaks)
+    ctx = Context(cell, len(used), model.work(cell.cfg, feed), steps, window_s,
+                  setup_s, memory_peak, compiles.count, reduced, peaks)
     metrics = {}
     for m in cell.metrics:
         v = cell.readers[m["name"]].read(ctx)

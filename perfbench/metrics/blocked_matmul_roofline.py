@@ -1,7 +1,7 @@
 """Share of its roofline that the blocked matmul kernel reaches: the
-least time for the products the steps require at their unpadded shapes
-(2·m·k·n operations, (mk + kn + mn)·4 bytes), over the device time of
-the kernel's events in the trace."""
+least time for the products the steps require of each chip at their
+unpadded shapes (2·m·k·n operations, (mk + kn + mn)·4 bytes), over the
+device time of the kernel's events on all the chips in the trace."""
 
 from perfbench.metrics_common import roofline_share
 

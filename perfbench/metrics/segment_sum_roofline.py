@@ -1,8 +1,8 @@
 """Share of its roofline that the Σ kernel reaches: the least time the
-chip could take for the Σ calls the steps require (the larger of their
-operations over the bf16 peak and their bytes over the HBM peak,
-counted from the model's shapes), over the device time of the kernel's
-events in the trace."""
+chips could take for the Σ calls the steps require of each (the larger
+of their operations over the bf16 peak and their bytes over the HBM
+peak, counted from the model's shapes), over the device time of the
+kernel's events on all the chips in the trace."""
 
 from perfbench.metrics_common import roofline_share
 

@@ -1,7 +1,8 @@
-"""The whole step's share of the chip's bf16 peak: the operations the
-forward and backward passes require, counted from the shapes (nothing
-recomputed counts), times the steps of the traced window, over the
-window and the peak, in %."""
+"""The whole step's share of its chips' bf16 peak: the operations the
+forward and backward passes require on all the chips, counted from the
+shapes (nothing recomputed counts), times the steps of the traced
+window, over the window and the cell's chips times one chip's peak, in
+%."""
 
 LAYER = "model step"
 MOVES = "step_ms"
@@ -11,4 +12,5 @@ def read(ctx):
     if ctx.trace is None or ctx.peaks is None:
         return None
     flops = ctx.work["flops"] * ctx.steps
-    return 100.0 * flops / (ctx.trace.window_s * ctx.peaks["bf16_flops_per_s"])
+    peak = ctx.chips * ctx.peaks["bf16_flops_per_s"]
+    return 100.0 * flops / (ctx.trace.window_s * peak)

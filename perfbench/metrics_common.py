@@ -18,17 +18,26 @@ def roofline_s(calls, peaks: dict) -> float:
                for f, b in calls)
 
 
+def least_s(ctx, calls) -> float:
+    """Least seconds of one step's ``calls`` of a kernel summed over the
+    chips: ``calls`` are what each chip makes (``models.work``), and a
+    trace's kernel time sums every chip's events."""
+    return ctx.chips * roofline_s(calls, ctx.peaks)
+
+
 def roofline_share(ctx, kernel: str, pattern: str):
     """% of its roofline the kernel reached over the traced window: the
-    least time of the calls the model says a step makes through it, over
-    the device time of the kernel's events. None (nothing to read) where
-    the trace does not hold exactly those calls, one event each per
-    step: the kernel is then off the path, or the calls it makes are not
-    the ones whose work is counted."""
+    least time of the calls the model says a step makes through it on
+    each chip, over the device time of the kernel's events on all chips.
+    None (nothing to read) where the trace does not hold exactly those
+    calls, one event each per step and chip
+    (``steps × len(calls) × chips``): the kernel is then off the path, or
+    the calls it makes are not the ones whose work is counted."""
     calls = ctx.work["kernels"].get(kernel)
     if ctx.trace is None or ctx.peaks is None or not calls:
         return None
-    if trace_reduce.kernel_calls(ctx.trace, pattern) != ctx.steps * len(calls):
+    events = ctx.steps * len(calls) * ctx.chips
+    if trace_reduce.kernel_calls(ctx.trace, pattern) != events:
         return None
     spent = trace_reduce.kernel_s(ctx.trace, pattern)
-    return 100.0 * ctx.steps * roofline_s(calls, ctx.peaks) / spent
+    return 100.0 * ctx.steps * least_s(ctx, calls) / spent

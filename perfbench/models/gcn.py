@@ -11,6 +11,7 @@ change the benchmark's traffic.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -22,6 +23,7 @@ from repro.optim import adam_init, adam_update
 from repro.relational import gcn_conv, rel_linear
 
 from perfbench import check
+from perfbench.models import one_chip
 
 #: Adam's first-moment decay, as ``repro.optim.adam_update`` defaults it.
 B1 = 0.9
@@ -57,7 +59,8 @@ def _init_params(key, feat: int, hidden: int, classes: int) -> dict:
     }
 
 
-def make_inputs(cfg: dict, feed, seed: int) -> dict:
+def make_inputs(cfg: dict, feed, seed: int, devices) -> dict:
+    one_chip(devices)
     g = synthetic_graph(cfg["nodes"], cfg["edges"], cfg["features"],
                         cfg["classes"], seed)
     out = {k: jax.device_put(v) for k, v in g.items()}
@@ -87,7 +90,8 @@ class Trainer:
     """The user's training loop: one jitted step of the relational ops,
     traced under the session that holds the Edge relation."""
 
-    def __init__(self, cfg: dict, feed, inputs: dict, spans):
+    def __init__(self, cfg: dict, feed, inputs: dict, spans, devices):
+        one_chip(devices)
         if not feed.full:
             raise ValueError("the GCN trains on the full graph only")
         n = rows(cfg)
@@ -136,15 +140,54 @@ def work(cfg: dict, feed) -> dict:
     convolutions (D = features, hidden) and one backward (D = hidden:
     the loss is not differentiated by the features). The Σ kernel's
     share is its adds, with E·D·4 bytes of messages, E·4 of ids and
-    S·D·4 of output. The dense products: two forward, and backward the
-    two weight gradients and the hidden layer's input gradient; the
-    program takes the two forward ones through the blocked matmul kernel
-    and the backward ones as XLA dots."""
+    S·D·4 of output. The gather kernel takes the three convolutions'
+    inputs by source, one row per edge: E·D·4 bytes read, E·D·4 written
+    and E·4 of row ids, and no arithmetic. The dense products: two
+    forward, and backward the two weight gradients and the hidden
+    layer's input gradient; the program takes the two forward ones
+    through the blocked matmul kernel and the backward ones as XLA dots.
+    E counts the self loops and no padding."""
     n, f, h, c = (int(cfg[k]) for k in ("nodes", "features", "hidden", "classes"))
     e = int(cfg["edges"]) + n  # a self loop per node
     segsum = [(e * d, 4 * (e * d + e + n * d)) for d in (f, h, h)]
+    gathers = [(0, 4 * (2 * e * d + e)) for d in (f, h, h)]
     forward = [_mm(n, f, h), _mm(n, h, c)]
     backward = [_mm(h, n, c), _mm(n, c, h), _mm(f, n, h)]  # dW2, dH, dW1
     flops = 2 * e * (f + h + h) + sum(fl for fl, _ in forward + backward)
     return {"flops": flops,
-            "kernels": {"segment_sum": segsum, "blocked_matmul": forward}}
+            "kernels": {"segment_sum": segsum, "gather_join": gathers,
+                        "blocked_matmul": forward}}
+
+
+FAULTS = ("unchanged", "half_batch")
+
+
+def _unchanged_adam(params, grads, state, **kw):
+    return params, state
+
+
+def _half_xent(xent):
+    def half(logits, y):
+        return xent(logits[::2], y[::2])
+    return half
+
+
+@contextlib.contextmanager
+def fault(name: str):
+    """This driver's step broken while entered, for the tests that see
+    ``correct`` come out false: ``unchanged`` skips Adam's update, so the
+    state stays as it was; ``half_batch`` takes the loss's mean over
+    every second node alone. A step built inside traces the broken
+    code."""
+    global adam_update, _xent
+    saved = adam_update, _xent
+    if name == "unchanged":
+        adam_update = _unchanged_adam
+    elif name == "half_batch":
+        _xent = _half_xent(_xent)
+    else:
+        raise ValueError(f"unknown fault {name!r}; have {FAULTS}")
+    try:
+        yield
+    finally:
+        adam_update, _xent = saved

@@ -7,6 +7,7 @@ table stays on the device, cut into the feed's batches in set-up."""
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -14,8 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro
+from repro.core.relation import DenseRelation
+from repro.core.session import QueryHandle
 
 from perfbench import check
+from perfbench.models import one_chip
 
 LOGREG_SQL = """
 mm   := SELECT Rx.row, SUM(multiply(Rx.val, theta.val))
@@ -58,9 +62,10 @@ def _make_batches(key, n: int, m: int, ids):
             [ys[i] for i in range(ids.shape[0])], theta)
 
 
-def make_inputs(cfg: dict, feed, seed: int) -> dict:
+def make_inputs(cfg: dict, feed, seed: int, devices) -> dict:
     """Full batch: ``x``, ``y``, ``theta``. Mini-batch: the table on the
     device as the feed's batches, ``xs[i]`` / ``ys[i]``, and ``theta``."""
+    one_chip(devices)
     key = jax.random.fold_in(jax.random.PRNGKey(seed % 2**32), seed >> 32)
     n, m = rows(cfg), int(cfg["features"])
     if feed.full:
@@ -74,7 +79,8 @@ class Trainer:
     """The user's loop: put the step's rows (mini-batch), step the
     handle, update theta and put it back."""
 
-    def __init__(self, cfg: dict, feed, inputs: dict, spans):
+    def __init__(self, cfg: dict, feed, inputs: dict, spans, devices):
+        one_chip(devices)
         self.spans = spans
         self.lr = lr(cfg, feed)
         self.theta = inputs["theta"]
@@ -128,3 +134,49 @@ def work(cfg: dict, feed) -> dict:
     r, m = feed.batch_rows, int(cfg["features"])
     mm = (2 * r * m, 4 * (r * m + m + r))
     return {"flops": 2 * mm[0], "kernels": {"blocked_matmul": [mm, mm]}}
+
+
+FAULTS = ("unchanged", "half_batch")
+
+
+def _unchanged(step):
+    def unchanged(self, **kw):
+        out, grads = step(self, **kw)
+        return out, {k: DenseRelation(jnp.zeros_like(g.data), g.key_arity)
+                     for k, g in grads.items()}
+    return unchanged
+
+
+def _half_batch(step):
+    def half(self, **kw):
+        db = self.db
+        full = {n: db.get(n) for n in ("Rx", "Ry")}
+        rows = full["Rx"].data.shape[0] // 2
+        for n, rel in full.items():
+            db.put(n, DenseRelation(rel.data[:rows], rel.key_arity))
+        try:
+            out, grads = step(self, **kw)
+        finally:
+            for n, rel in full.items():
+                db.put(n, rel)
+        two = lambda r: DenseRelation(2 * r.data, r.key_arity)  # noqa: E731
+        return two(out), {k: two(g) for k, g in grads.items()}
+    return half
+
+
+@contextlib.contextmanager
+def fault(name: str):
+    """The program's ``QueryHandle.step`` broken while entered, for the
+    tests that see ``correct`` come out false: ``unchanged`` returns a
+    zero gradient, so θ stays as it was; ``half_batch`` steps on the
+    first half of the step's rows and doubles the summed loss and
+    gradient, the mean taken over the rest."""
+    wrap = {"unchanged": _unchanged, "half_batch": _half_batch}.get(name)
+    if wrap is None:
+        raise ValueError(f"unknown fault {name!r}; have {FAULTS}")
+    step = QueryHandle.step
+    QueryHandle.step = wrap(step)
+    try:
+        yield
+    finally:
+        QueryHandle.step = step

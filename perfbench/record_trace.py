@@ -32,10 +32,11 @@ def record(root: pathlib.Path, workload: str, seed: int, steps: int,
     from perfbench.feed import Feed
 
     cell = harness.load_cell(root, workload, trace=False)
+    devices = jax.devices()[:cell.chips]
     spans = harness.Spans(True)
     feed = Feed(cell.traffic, cell.model.rows(cell.cfg), seed)
-    inputs = cell.model.make_inputs(cell.cfg, feed, seed)
-    trainer = cell.model.Trainer(cell.cfg, feed, inputs, spans)
+    inputs = cell.model.make_inputs(cell.cfg, feed, seed, devices)
+    trainer = cell.model.Trainer(cell.cfg, feed, inputs, spans, devices)
     jax.block_until_ready(trainer.step(0))
     tdir = tempfile.mkdtemp(prefix="perfbench-trace-")
     try:

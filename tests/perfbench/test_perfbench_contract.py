@@ -8,10 +8,11 @@ import ast
 import importlib
 import json
 import math
+import pathlib
 import re
 
 import pytest
-from conftest import REPO
+from conftest import REPO, tiny_overlay
 
 from perfbench import check, harness
 
@@ -74,6 +75,20 @@ def test_config_file(config):
     assert cfg["reduced"] == config["reduced"]
     importlib.import_module(f"perfbench.models.{cfg['model']}")
     importlib.import_module(f"perfbench.reference.{cfg['model']}")
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_has_tiny_overlays(cell):
+    """Every configuration and mix a cell uses has an overlay under
+    ``perfbench/tiny/`` (``{}`` where it runs as it is on the CPU), so
+    that the CPU tests never run a cell at its published size."""
+    w = {w["name"]: w for w in BENCH["workloads"]}[cell]
+    config = {c["name"]: c for c in BENCH["configs"]}[w["config"]]
+    for rel in (pathlib.Path(config["file"]).relative_to("perfbench"),
+                pathlib.Path("traffic") / f"{w['traffic']}.json"):
+        overlay = tiny_overlay(REPO, rel)
+        assert overlay.is_file(), f"{cell}: no overlay {overlay.relative_to(REPO)}"
+        assert isinstance(json.loads(overlay.read_text()), dict), overlay
 
 
 @pytest.mark.parametrize(

@@ -10,18 +10,17 @@ import types
 
 import jax
 import pytest
-from conftest import REPO, run_cell
+from conftest import DATA, RECORDED, REPO, recorded_ctx, run_cell
 
 from perfbench import harness
 from perfbench import program_spans as ps
 from perfbench import trace_reduce as tr
 from perfbench.models import gcn
 
-DATA = REPO / "perfbench" / "testdata"
 METRICS = REPO / "perfbench" / "metrics"
 V5E = harness.peaks_for("TPU v5 lite")
 HOST = ("session.put_ms", "session.handle_ms", "engine.lookup_ms", "engine.call_ms")
-GCN = json.loads((REPO / "perfbench" / "configs" / "gcn-arxiv.json").read_text())
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 
 
 def _reader(metric):
@@ -36,9 +35,9 @@ def _red(ops=(), bench=(), program=None, window=(0, 1000)):
     return red
 
 
-def _ctx(red, steps, cfg=None):
-    return types.SimpleNamespace(trace=red, steps=steps, peaks=V5E,
-                                 cell=types.SimpleNamespace(cfg=cfg or {}))
+def _ctx(red, steps, work=None):
+    return types.SimpleNamespace(trace=red, steps=steps, peaks=V5E, chips=1,
+                                 work=work or {"flops": 0, "kernels": {}})
 
 
 # two steps: lookup (lowering inside) and call (dispatch inside) under
@@ -104,21 +103,25 @@ def _launches(n):
             for i in range(n)]
 
 
+SMALL = {"nodes": 10, "edges": 30, "features": 4, "hidden": 8, "classes": 3}
+
+
 @pytest.mark.parametrize("launches, reads", [(6, True), (12, True), (5, False),
                                              (0, False), (7, False)])
 def test_gather_roofline_needs_whole_launches_per_gather(launches, reads):
     read = _reader("gather_join_roofline")
     ops = _launches(launches) + [("%segment_sum.3 = custom-call tpu_custom_call", 900, 950)]
-    got = read(_ctx(_red(ops=ops), steps=2, cfg=GCN))
+    got = read(_ctx(_red(ops=ops), steps=2, work=gcn.work(SMALL, None)))
     if not reads:
         assert got is None
         return
-    e = GCN["edges"] + GCN["nodes"]
-    least = sum(4 * (2 * e * d + e) for d in (128, 256, 256)) / V5E["hbm_bytes_per_s"]
+    e = SMALL["edges"] + SMALL["nodes"]
+    least = sum(4 * (2 * e * d + e) for d in (4, 8, 8)) / V5E["hbm_bytes_per_s"]
     assert got == pytest.approx(100 * 2 * least / (launches * 10e-9))
 
 
-@pytest.mark.parametrize("name", ["gcn-arxiv.full", "logreg-epsilon.full"])
+@pytest.mark.parametrize("name", [n for n, rec in RECORDED.items()
+                                  if not rec["program_spans"]])
 def test_trace_without_program_spans_reduces_as_before(name):
     with gzip.open(DATA / f"{name}.xplane.pb.gz", "rb") as f:
         profile = jax.profiler.ProfileData.from_serialized_xspace(f.read())
@@ -131,14 +134,17 @@ def test_trace_without_program_spans_reduces_as_before(name):
 
 def test_recorded_minibatch_trace_splits_the_session_host_time():
     """A few mini-batch steps on one v5e with the program's spans: the
-    four host metrics read, and together they are the benchmark's own
-    ``put`` and ``step`` spans to within 5 %."""
-    red = tr.load(DATA / "logreg-epsilon.minibatch.xplane.pb.gz")
+    four host metrics (under the names the trace's file gives) read, and
+    together they are the benchmark's own ``put`` and ``step`` spans to
+    within 5 %."""
+    (name, rec), = [(n, rec) for n, rec in RECORDED.items() if "host_suffix" in rec]
+    red = tr.load(DATA / f"{name}.xplane.pb.gz")
     steps = sum(1 for n, _, _ in red.spans if n == "wait")
     ctx = _ctx(red, steps)
-    parts = {m: _reader(m + ".minibatch")(ctx) for m in HOST}
+    suffix = rec["host_suffix"]
+    parts = {m: _reader(m + suffix)(ctx) for m in HOST}
     assert all(v is not None and v > 0 for v in parts.values()), parts
-    host = _reader("session.host_ms.minibatch")(ctx)
+    host = _reader("session.host_ms" + suffix)(ctx)
     assert sum(parts.values()) == pytest.approx(host, rel=0.05)
     names = {n for n, _, _, _ in ps.program(red)}
     assert {"engine.lower", "engine.plan"}.isdisjoint(names)
@@ -153,21 +159,23 @@ def test_recorded_gcn_trace_reads_the_gather_roofline():
     """A GCN window on one v5e with the gather's launches named: the
     gather's roofline reads, the other kernels read as before, and no
     program span runs in the window (the user's jitted step is cached)."""
-    red = tr.load(DATA / "gcn-arxiv.full.gather_join.xplane.pb.gz")
-    steps = sum(1 for n, _, _ in red.spans if n == "wait")
-    ctx = _ctx(red, steps, cfg=GCN)
+    (name,) = [n for n, rec in RECORDED.items()
+               if "gather_join_roofline" in rec["reads"]]
+    ctx = recorded_ctx(name)
+    red = ctx.trace
     assert tr.kernel_calls(red, r"%closed_call") == 0
     share = _reader("gather_join_roofline")(ctx)
     assert share is not None and 0 < share < 100
-    ctx.work = gcn.work(GCN, None)
     for metric in ("segment_sum_roofline", "blocked_matmul_roofline"):
         assert 0 < _reader(metric)(ctx) < 100
     w0, w1 = red.window
     assert not [sp for sp in ps.program(red) if w0 <= sp[1] and sp[2] <= w1]
 
 
-@pytest.mark.parametrize("cell, suffix", [("logreg-epsilon.full", ""),
-                                          ("logreg-epsilon.minibatch", ".minibatch")])
+@pytest.mark.parametrize("cell, suffix", [
+    (w["name"], suffix) for w in BENCH["workloads"] for suffix in ("", ".minibatch")
+    if any(m["name"] == HOST[0] + suffix and w["name"] in m.get("workloads", [])
+           for m in BENCH["per_layer"])])
 def test_traced_run_reports_the_host_metrics(tiny_root, cell, suffix):
     rc, res, err = run_cell(tiny_root, cell, trace=True)
     assert rc == 0 and res["correct"], err

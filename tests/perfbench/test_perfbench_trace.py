@@ -9,11 +9,11 @@ import re
 
 import jax
 import pytest
-from conftest import REPO
+from conftest import DATA, RECORDED, REPO, recorded_ctx
 
+from perfbench import harness
 from perfbench import trace_reduce as tr
 
-DATA = REPO / "perfbench" / "testdata"
 METRICS = REPO / "perfbench" / "metrics"
 
 
@@ -46,10 +46,12 @@ def test_gap_outside_every_span_is_none():
     assert tr.idle_gaps(red) == [["none", pytest.approx(50e-9)]]
 
 
-def _pattern(metric):
-    from perfbench import harness
+def _reader(metric):
+    return harness._module(METRICS / f"{metric}.py", f"reader_{metric}")
 
-    return harness._module(METRICS / f"{metric}.py", f"reader_{metric}").PATTERN
+
+def _pattern(metric):
+    return _reader(metric).PATTERN
 
 
 def _events(path, red):
@@ -64,12 +66,12 @@ def _events(path, red):
             if e.end_ns > w0 and e.start_ns < w1]
 
 
-# Recorded on one v5e by a traced run of each cell (two GCN steps at
-# ogbn-arxiv shape; 37 full-batch logistic-regression steps).
+# Recorded on one v5e by a traced run of a cell; its file beside it says
+# which kernel runs how many times a step, and under which spans.
 @pytest.mark.parametrize("name, kernel, per_step, labels", [
-    ("gcn-arxiv.full", "segment_sum_roofline", 3, {"step", "wait"}),
-    ("logreg-epsilon.full", "blocked_matmul_roofline", 2,
-     {"put", "step", "update", "wait"}),
+    (name, kernel, per_step, set(rec["idle_labels"]))
+    for name, rec in RECORDED.items()
+    for kernel, per_step in rec.get("calls_per_step", {}).items()
 ])
 def test_recorded_trace_reduces_as_its_events_say(name, kernel, per_step, labels):
     path = DATA / f"{name}.xplane.pb.gz"
@@ -96,3 +98,17 @@ def test_recorded_trace_reduces_as_its_events_say(name, kernel, per_step, labels
     assert sum(s for _, s in gaps) == pytest.approx(
         red.window_s - tr.busy_s(red), rel=1e-9)
     assert {lab for lab, _ in gaps} <= labels | {"none"}
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_recorded_trace_reads_as_recorded(name):
+    """The readers of the metrics that count work from the model's shapes
+    read, from a trace of one chip, the very numbers written beside it
+    when it was recorded: counting work per chip changes nothing on
+    one."""
+    ctx = recorded_ctx(name)
+    assert ctx.chips == 1
+    reads = RECORDED[name]["reads"]
+    assert reads
+    for metric, value in reads.items():
+        assert _reader(metric).read(ctx) == value, metric

@@ -22,6 +22,7 @@ def test_gcn_work_by_hand():
         (40 * 8, 4 * (40 * 8 + 40 + 10 * 8)),
         (40 * 8, 4 * (40 * 8 + 40 + 10 * 8)),
     ]
+    assert w["kernels"]["gather_join"] == [(0, 4 * (2 * 40 * d + 40)) for d in (4, 8, 8)]
     # the kernel takes the two forward products; all five count as work
     mm = w["kernels"]["blocked_matmul"]
     assert mm == [(2 * 10 * 4 * 8, 4 * (10 * 4 + 4 * 8 + 10 * 8)),
@@ -54,12 +55,14 @@ def test_roofline_takes_the_binding_bound():
     assert metrics_common.roofline_s(calls, V5E) == pytest.approx(1e-3 + 2e-3)
 
 
-def _ctx(ops, steps=2, work=None):
-    red = trace_reduce.Reduced((0, 10_000_000), {"/device:TPU:0": ops},
+def _ctx(ops, steps=2, work=None, chips=1):
+    """A context whose trace holds ``ops`` on each of ``chips`` chips."""
+    red = trace_reduce.Reduced((0, 10_000_000),
+                               {f"/device:TPU:{i}": list(ops) for i in range(chips)},
                                [("window", 0, 10_000_000)])
-    return harness.Context(cell=None, work=work, steps=steps, window_s=0.01,
-                           setup_s=1.0, memory_peak_bytes=2**30, compiles=0,
-                           trace=red, peaks=V5E)
+    return harness.Context(cell=None, chips=chips, work=work, steps=steps,
+                           window_s=0.01, setup_s=1.0, memory_peak_bytes=2**30,
+                           compiles=0, trace=red, peaks=V5E)
 
 
 def _reader(name):
@@ -92,3 +95,33 @@ def test_mfu_and_idle_readers():
     assert _reader("step.mfu").read(ctx) == pytest.approx(50.0)
     assert _reader("device.idle_share").read(ctx) == pytest.approx(50.0)
     assert _reader("peak_hbm_gib").read(ctx) == 1.0
+
+
+GATHER = '%gather_join.7 = f32[65536,1,8] custom-call(), custom_call_target="tpu_custom_call"'
+
+
+def test_shares_hold_on_four_chips():
+    """One chip's trace copied to four planes: each chip makes the calls
+    the model counts for it, so the kernels' shares read as on one chip,
+    and the whole step's share of four chips' peak is a quarter."""
+    work = {"flops": 197e9, "kernels": {"segment_sum": [(0, 819e6)],
+                                        "gather_join": [(0, 819e6)] * 2}}
+    ops = [trace_reduce.Op("k", 0, 2_000_000, SIGMA),
+           trace_reduce.Op("k", 2_000_000, 4_000_000, SIGMA),
+           trace_reduce.Op("g", 4_000_000, 6_000_000, GATHER),
+           trace_reduce.Op("g", 6_000_000, 7_000_000, GATHER),
+           trace_reduce.Op("g", 7_000_000, 9_000_000, GATHER),
+           trace_reduce.Op("g", 9_000_000, 10_000_000, GATHER)]
+    one, four = _ctx(ops, work=work), _ctx(ops, work=work, chips=4)
+    assert four.trace.window_s == one.trace.window_s
+    for metric in ("segment_sum_roofline", "gather_join_roofline"):
+        assert _reader(metric).read(one) is not None
+        assert _reader(metric).read(four) == pytest.approx(_reader(metric).read(one))
+    assert _reader("segment_sum_roofline").read(one) == pytest.approx(50.0)
+    assert _reader("gather_join_roofline").read(one) == pytest.approx(200 * 2 / 6)
+    assert _reader("step.mfu").read(four) == pytest.approx(_reader("step.mfu").read(one) / 4)
+    # one chip's events alone under a four-chip cell: not the counted calls
+    alone = _ctx(ops, work=work)
+    alone.chips = 4
+    assert _reader("segment_sum_roofline").read(alone) is None
+    assert _reader("gather_join_roofline").read(alone) is None
