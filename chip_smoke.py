@@ -34,8 +34,10 @@ same step on one device in the same process: the GCN gradient step with
 the edge nnz rows sharded four ways (``partitioned_edges`` on
 ``make_host_mesh(model=1)``), and the logistic-regression step on
 ``Database(mesh="host:2")``. On a mesh of more than one device the
-dispatch table has no ``pallas`` tier (``kernels.table_for_mesh``); the
-resolutions printed show it.
+dispatch table has no ``pallas`` tier for what XLA's partitioner splits
+(``kernels.table_for_mesh``), and keeps it for the GCN's join-aggregates,
+which run per shard inside ``shard_map``; the resolutions printed show
+it.
 
 Data is random, made from ``--seed``. Step times are smoke timings, not
 benchmark numbers. The last line of standard output is one JSON object,
@@ -336,8 +338,9 @@ def sharded_phase(chips: int, seed: int, *, arxiv=ARXIV_SHAPE,
                   tier: str = "pallas") -> list:
     """GCN (edge nnz rows sharded ``chips`` ways) and logreg (2 × 2 mesh)
     steps, each against the same step on one device; returns failures.
-    On the mesh the ``pallas`` tier gives way to ``jnp``: the GCN query
-    has no dense product, logreg's products need not round alike."""
+    On the mesh the GCN's sites run per shard inside ``shard_map`` and
+    keep the tier; logreg's products, which XLA's partitioner splits,
+    give ``pallas`` way to ``jnp`` (they need not round alike)."""
     tiers = (tier, "jnp" if tier == "pallas" else tier)
     a = arxiv
     g = synthetic_graph(a["nodes"], a["edges"], a["feat"], a["classes"], seed=seed)
@@ -351,7 +354,7 @@ def sharded_phase(chips: int, seed: int, *, arxiv=ARXIV_SHAPE,
         return db.query(query)
 
     bad = _mesh_vs_one("gcn nnz-sharded", gcn, ("Edge", "Node"),
-                       make_host_mesh(model=1), tiers, RTOL)
+                       make_host_mesh(model=1), (tier, tier), RTOL)
     del edge, g
     gc.collect()
 

@@ -7,6 +7,11 @@ ops over chunked relations:
   Σ(grp, +, ⋈(eq-pred, proj, mul/matmul, ·, ·)) over DenseRelations
       → one ``jnp.einsum`` (block axes from the join's key-equivalence
         classes, chunk axes from the kernel's chunk_spec);
+  Σ(grp, +, ⋈(COO, Dense)) (graph convolution and its gradient)
+      → one fused gather → ⊗ → ``segment_sum`` over blocks of nnz rows
+        (``_coo_join_agg``): no nnz × width array beyond one block, and
+        per shard inside ``shard_map`` with the partial sums
+        reduce-scattered (``exchange``) where the plan shards the nnz rows;
   joins against a CooRelation (graph edges)   → gather (``take``);
   Σ over a CooRelation                        → ``segment_sum`` (scatter-add);
   RJP broadcast/aligned joins (from Σ/σ differentiation)
@@ -40,17 +45,20 @@ additive aggregation semantics this is exact.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import string
-from typing import Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, FrozenSet, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import fra, kernels
 from .kernels import BinKernel
 from .keys import In, JoinPred, JoinProj, KeyFn, L, Lit, R, join_equiv_classes
-from .relation import CooRelation, DenseRelation
+from .relation import COO_PAD_KEY, CooRelation, DenseRelation
+from .spans import EXCHANGE, JOIN_AGG
 
 AnyRel = Union[DenseRelation, CooRelation]
 Env = Dict[str, AnyRel]
@@ -105,7 +113,9 @@ def _norm_pairs(pred: JoinPred):
 def _note(
     resolutions: Optional[Dict], op: str, site: str, impl, info: Optional[Dict] = None
 ) -> None:
-    """Record a dispatch decision for diagnostics (Compiled.resolutions).
+    """Record a dispatch decision for diagnostics (Compiled.resolutions):
+    ``impl`` is the resolved ``KernelImpl``, or the tier name of a
+    composite site (the fused join-aggregate), which records no info.
     Distinct sites that share a shape signature get ordinal suffixes
     (``op[site]#2`` …) so the record counts every decision, not every
     distinct shape. When ``resolutions`` is a ``kernels.ResolutionLog``
@@ -120,9 +130,10 @@ def _note(
         while f"{key}#{i}" in resolutions:
             i += 1
         key = f"{key}#{i}"
-    resolutions[key] = impl.tier
+    tier = getattr(impl, "tier", impl)
+    resolutions[key] = tier
     if info is not None and hasattr(resolutions, "record"):
-        resolutions.record(key, op, site, impl.tier, dict(info))
+        resolutions.record(key, op, site, tier, dict(info))
 
 
 def _dispatched_matmul_join(
@@ -491,9 +502,10 @@ def _mask_padded_rows(keys: jnp.ndarray, vals: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-def _coo_join(
-    join: fra.Join, lrel: AnyRel, rrel: AnyRel, dispatch, resolutions
-) -> CooRelation:
+def _coo_operands(join: fra.Join, lrel: AnyRel, rrel: AnyRel):
+    """``(coo, dense, coo_is_left, d2c)`` of a COO ⋈ Dense join: ``d2c``
+    maps each dense key component to the COO key column it is gathered
+    at."""
     coo_is_left = isinstance(lrel, CooRelation)
     coo = lrel if coo_is_left else rrel
     dense = rrel if coo_is_left else lrel
@@ -511,6 +523,13 @@ def _coo_join(
         raise LoweringError(
             "COO join requires every dense key component matched (gather)"
         )
+    return coo, dense, coo_is_left, d2c
+
+
+def _coo_join(
+    join: fra.Join, lrel: AnyRel, rrel: AnyRel, dispatch, resolutions
+) -> CooRelation:
+    coo, dense, coo_is_left, d2c = _coo_operands(join, lrel, rrel)
     idx = tuple(coo.keys[:, d2c[j]] for j in range(dense.key_arity))
     gathered = _dispatched_gather(dense, idx, dispatch, resolutions)
     kfn = _vmapped(join.kernel.fn, 1)
@@ -537,6 +556,221 @@ def _coo_join(
         extents.append(ext)
     keys = jnp.stack(cols, axis=1) if cols else jnp.zeros((coo.nnz, 0), coo.keys.dtype)
     return CooRelation(keys, vals, tuple(extents))
+
+
+# ---------------------------------------------------------------------------
+# Fused join-aggregate: Σ(COO ⋈ Dense) in blocks of nnz rows, per shard
+# ---------------------------------------------------------------------------
+
+#: The most bytes of messages (nnz rows × the width of ⊗'s output) that
+#: one block of a fused join-aggregate materialises. A call whose whole
+#: message array fits is lowered as one gather, ⊗ and Σ; a larger one
+#: loops over blocks of nnz rows. At ogbn-arxiv shape the largest call,
+#: 1,335,586 edges × 256 × 4 B = 1.37 GB, is one block.
+JOIN_AGG_BLOCK_BYTES = 1_400_000_000
+
+#: A block's nnz rows are a multiple of this (the row gather's launch).
+BLOCK_ROWS_QUANTUM = 65_536
+
+#: A row-sharded table's rows a shard are a multiple of this (whole TPU
+#: tiles of any dtype).
+TABLE_ROWS_QUANTUM = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class NnzShards:
+    """The environment's COO relations, by name, whose nnz rows the plan
+    puts on the data ``axes`` of ``mesh`` (``data:shard_nnz_*``). Their
+    join-aggregates run per shard inside ``shard_map``; the partial sums
+    meet in ``exchange``."""
+
+    mesh: Any
+    axes: Tuple[str, ...]
+    names: FrozenSet[str]
+
+    @property
+    def size(self) -> int:
+        return math.prod(int(self.mesh.shape[a]) for a in self.axes)
+
+    def spec(self, ndim: int) -> P:
+        """Rows over the data axes, the other ``ndim - 1`` dims whole."""
+        ax = self.axes if len(self.axes) > 1 else self.axes[0]
+        return P(ax, *([None] * (ndim - 1)))
+
+
+def exchange(partial: jnp.ndarray, axes: Tuple[str, ...], *, scatter: bool) -> jnp.ndarray:
+    """Combine the shards' partial sums of a join-aggregate over the data
+    ``axes``, inside ``shard_map``: a reduce-scatter of the rows, so each
+    shard owns its contiguous 1/n of them, or an all-reduce where the
+    output is replicated (a Σ with no output key). Every partial sum that
+    crosses chips goes through here."""
+    with jax.named_scope(EXCHANGE):
+        if scatter:
+            return jax.lax.psum_scatter(partial, axes, scatter_dimension=0, tiled=True)
+        return jax.lax.psum(partial, axes)
+
+
+def _dispatched_segment_sum(
+    values: jnp.ndarray, flat: jnp.ndarray, num: int, dispatch, resolutions
+) -> jnp.ndarray:
+    """Σ of ``values`` (E, *chunk) by flat segment ids into ``num`` rows
+    through the ``segment_sum`` dispatch op: (num, *chunk)."""
+    e, chunk = values.shape[0], values.shape[1:]
+    d = math.prod(chunk)
+    info = {"nnz": e, "dim": d, "num_segments": num, "dtype": values.dtype}
+    impl = kernels.resolve_impl("segment_sum", info, dispatch)
+    _note(resolutions, "segment_sum", f"E={e},D={d},S={num}", impl, info)
+    if impl.tier == "jnp":
+        return jax.ops.segment_sum(values, flat, num_segments=num)
+    return impl.fn(values.reshape((e, d)), flat, num).reshape((num,) + chunk)
+
+
+def block_rows(nnz: int, row_bytes: int) -> Tuple[int, int]:
+    """``(rows per block, blocks)`` for ``nnz`` message rows of
+    ``row_bytes`` each under ``JOIN_AGG_BLOCK_BYTES``: one block where
+    they all fit, else blocks of a multiple of ``BLOCK_ROWS_QUANTUM``
+    rows (the last one ragged)."""
+    if nnz * row_bytes <= JOIN_AGG_BLOCK_BYTES:
+        return nnz, 1
+    rows = JOIN_AGG_BLOCK_BYTES // max(row_bytes, 1)
+    rows = max(BLOCK_ROWS_QUANTUM, rows - rows % BLOCK_ROWS_QUANTUM)
+    return rows, -(-nnz // rows)
+
+
+def _row_blocks(arrays, rows: int, blocks: int, pad_values):
+    """``arrays`` (each with nnz leading rows) padded to ``blocks × rows``
+    rows with ``pad_values`` and split as (blocks, rows, ...)."""
+    out = []
+    for a, fill in zip(arrays, pad_values):
+        extra = blocks * rows - a.shape[0]
+        a = jnp.pad(a, ((0, extra),) + ((0, 0),) * (a.ndim - 1), constant_values=fill)
+        out.append(a.reshape((blocks, rows) + a.shape[1:]))
+    return out
+
+
+def _coo_join_agg(
+    join: fra.Join,
+    grp: KeyFn,
+    lrel: AnyRel,
+    rrel: AnyRel,
+    dispatch,
+    resolutions,
+    shards: Optional[NnzShards],
+) -> Optional[DenseRelation]:
+    """Σ_grp(COO ⋈ Dense) as one operation: gather each COO row's dense
+    row, apply ⊗, and Σ the results by the output key, one block of nnz
+    rows at a time (``block_rows``), so that no nnz × width array beyond
+    one block exists. ``shards`` set (the COO's nnz rows on the data
+    axes): each shard runs this on its own rows inside ``shard_map``, with
+    the dense side whole, and ``exchange`` reduce-scatters the partial
+    sums to the row owners. Returns None where the one-block lowering
+    (``_coo_join`` then Σ) is the same operation: a call that fits one
+    block on one device, or a shape this does not cover. Raises what
+    ``_coo_join`` raises for a join it cannot lower."""
+    coo, dense, coo_is_left, d2c = _coo_operands(join, lrel, rrel)
+    if dense.key_arity == 0 or any(isinstance(c, Lit) for c in grp.comps):
+        return None
+    seg_cols, extents = [], []
+    for c in grp.comps:
+        pc = join.proj.comps[c.idx]
+        if isinstance(pc, Lit):
+            return None
+        own = isinstance(pc, L) == coo_is_left
+        seg_cols.append(pc.idx if own else d2c[pc.idx])
+        extents.append(coo.extents[pc.idx] if own else dense.extents[pc.idx])
+    gather_cols = [d2c[j] for j in range(dense.key_arity)]
+    kfn = _vmapped(join.kernel.fn, 1)
+
+    def apply(v, g):
+        return kfn(v, g) if coo_is_left else kfn(g, v)
+
+    out = jax.eval_shape(
+        apply,
+        jax.ShapeDtypeStruct((1,) + coo.chunk_shape, coo.values.dtype),
+        jax.ShapeDtypeStruct((1,) + dense.chunk_shape, dense.data.dtype),
+    )
+    chunk, dtype = out.shape[1:], out.dtype
+    n = shards.size if shards is not None else 1
+    if coo.nnz == 0 or coo.nnz % n:
+        return None
+    e = coo.nnz // n
+    d = math.prod(chunk)
+    rows, blocks = block_rows(e, d * jnp.dtype(dtype).itemsize)
+    num = math.prod(extents)
+    # padded up so that the exchange hands each shard an equal run of rows
+    num_pad = -(-num // n) * n if extents else 1
+    inner = dispatch.per_shard() if shards is not None and dispatch is not None else dispatch
+    g = kernels.resolve_impl("gather_join", {
+        "rows": rows, "num_rows": math.prod(dense.extents),
+        "dim": math.prod(dense.chunk_shape), "dtype": dense.data.dtype}, inner)
+    s = kernels.resolve_impl("segment_sum", {
+        "nnz": rows, "dim": d, "num_segments": num_pad, "dtype": dtype}, inner)
+    tier = g.tier if g.tier == s.tier else f"{g.tier}+{s.tier}"
+    _note(resolutions, "join_agg", f"E={e},D={d},S={num},blocks={blocks}", tier)
+    if shards is None and blocks == 1:
+        return None
+
+    def block_sum(table, keys, values):
+        idx = tuple(keys[:, c] for c in gather_cols)
+        gathered = _dispatched_gather(table, idx, inner, resolutions)
+        vals = _mask_padded_rows(keys, apply(values, gathered))
+        flat = jnp.zeros((keys.shape[0],), jnp.int32)
+        stride = 1
+        for col, ext in zip(reversed(seg_cols), reversed(extents)):
+            flat = flat + keys[:, col].astype(jnp.int32) * stride
+            stride *= ext
+        return _dispatched_segment_sum(vals, flat, num_pad, inner, resolutions)
+
+    def local(keys, values, data):
+        if blocks == 1:
+            return block_sum(DenseRelation(data, dense.key_arity), keys, values)
+        kb, vb = _row_blocks((keys, values), rows, blocks, (COO_PAD_KEY, 0))
+        # the loop carries the table in the row gather's (rows, 1, width)
+        # view: a TPU lays that out apart from (rows, width), and the one
+        # copy between them is then made once, before the loop, and not
+        # carried beside the table it copies
+        rows3 = data.reshape(math.prod(data.shape[:dense.key_arity]), 1, -1)
+
+        def body(i, acc):
+            table = DenseRelation(rows3.reshape(data.shape), dense.key_arity)
+            return acc + block_sum(table, kb[i], vb[i])
+
+        return jax.lax.fori_loop(0, blocks, body, jnp.zeros((num_pad,) + chunk, dtype))
+
+    # Each shard gathers from the whole table. A one-key table goes in
+    # row-sharded, padded to whole tiles a shard, and is all-gathered
+    # here: XLA's own all-gather of unevenly sharded rows (2,449,029 over
+    # four chips) compiles for minutes on a TPU.
+    gathered = shards is not None and dense.key_arity == 1
+    table = dense.data
+    if gathered:
+        pad = -dense.extents[0] % (n * TABLE_ROWS_QUANTUM)
+        table = jnp.pad(table, ((0, pad),) + ((0, 0),) * len(dense.chunk_shape))
+
+    def per_shard(keys, values, data):
+        if gathered:
+            with jax.named_scope(EXCHANGE):
+                data = jax.lax.all_gather(data, shards.axes, tiled=True)
+        return exchange(local(keys, values, data), shards.axes, scatter=bool(extents))
+
+    with jax.named_scope(JOIN_AGG):
+        if shards is None:
+            summed = local(coo.keys, coo.values, dense.data)
+        else:
+            summed = jax.shard_map(
+                per_shard,
+                mesh=shards.mesh,
+                in_specs=(
+                    shards.spec(2),
+                    shards.spec(coo.values.ndim),
+                    shards.spec(table.ndim) if gathered else P(),
+                ),
+                out_specs=shards.spec(1 + len(chunk)) if extents else P(),
+                check_vma=False,
+            )(coo.keys, coo.values, table)
+    return DenseRelation(
+        summed[:num].reshape(tuple(extents) + chunk), key_arity=len(extents)
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -585,11 +819,16 @@ def _restricted_join(
     rrel: AnyRel,
     dispatch=None,
     resolutions: Optional[Dict] = None,
+    shards: Optional[NnzShards] = None,
 ) -> CooRelation:
     """Evaluate a dense⋈dense join only at the key set of ``ref``: gather
     both operands per ref-tuple and apply the kernel pointwise. This is the
     sparse-gradient fast path (e.g. ∂loss/∂edge_weights = g[dst]·h[src]);
-    the per-tuple gathers route through the ``gather_join`` dispatch op."""
+    the per-tuple gathers route through the ``gather_join`` dispatch op.
+    Rows whose gathered operands exceed ``JOIN_AGG_BLOCK_BYTES`` run in
+    blocks of ref rows, so only the ref-shaped output is ever whole; with
+    ``shards`` (ref's nnz rows on the data axes) each shard takes its own
+    rows inside ``shard_map`` against the whole dense operands."""
     if not (isinstance(lrel, DenseRelation) and isinstance(rrel, DenseRelation)):
         raise LoweringError("restricted join requires dense operands")
     la, ra = join.left.key_arity, join.right.key_arity
@@ -597,35 +836,67 @@ def _restricted_join(
     if solved is None:
         raise LoweringError("restricted join underdetermined (needs Σ)")
     lex, rex = solved
-
-    def gather(rel: DenseRelation, exprs):
-        idx = []
-        for e in exprs:
-            if isinstance(e, Lit):
-                idx.append(jnp.full((ref.nnz,), e.val, dtype=ref.keys.dtype))
-            else:
-                idx.append(ref.keys[:, e])
-        return (
-            _dispatched_gather(rel, tuple(idx), dispatch, resolutions)
-            if idx
-            else jnp.broadcast_to(rel.data, (ref.nnz,) + rel.chunk_shape)
-        )
-
-    lv = gather(lrel, lex)
-    rv = gather(rrel, rex)
-    vals = _vmapped(join.kernel.fn, 1)(lv, rv)
-    vals = _mask_padded_rows(ref.keys, vals)
-    # Chunk-level broadcasting in the forward kernel (e.g. scalar edge
-    # weight × embedding chunk) dualizes to a reduction in the backward:
-    # sum the VJP chunk down to the target relation's chunk shape.
     tgt = ref.chunk_shape
-    extra = (vals.ndim - 1) - len(tgt)
-    if extra > 0:
-        vals = jnp.sum(vals, axis=tuple(range(1, 1 + extra)))
-    for ax, (got, want) in enumerate(zip(vals.shape[1:], tgt)):
-        if got != want:
-            assert want == 1, (vals.shape, tgt)
-            vals = jnp.sum(vals, axis=1 + ax, keepdims=True)
+    kfn = _vmapped(join.kernel.fn, 1)
+    if shards is not None and ref.nnz % shards.size:
+        shards = None
+    e = ref.nnz // (shards.size if shards is not None else 1)
+    table = dispatch.per_shard() if shards is not None and dispatch is not None else dispatch
+
+    def at_rows(keys, ldata, rdata):
+        nnz = keys.shape[0]
+
+        def gather(rel: DenseRelation, exprs):
+            idx = []
+            for e in exprs:
+                if isinstance(e, Lit):
+                    idx.append(jnp.full((nnz,), e.val, dtype=keys.dtype))
+                else:
+                    idx.append(keys[:, e])
+            return (
+                _dispatched_gather(rel, tuple(idx), table, resolutions)
+                if idx
+                else jnp.broadcast_to(rel.data, (nnz,) + rel.chunk_shape)
+            )
+
+        lv = gather(DenseRelation(ldata, lrel.key_arity), lex)
+        rv = gather(DenseRelation(rdata, rrel.key_arity), rex)
+        vals = _mask_padded_rows(keys, kfn(lv, rv))
+        # Chunk-level broadcasting in the forward kernel (e.g. scalar edge
+        # weight × embedding chunk) dualizes to a reduction in the backward:
+        # sum the VJP chunk down to the target relation's chunk shape.
+        extra = (vals.ndim - 1) - len(tgt)
+        if extra > 0:
+            vals = jnp.sum(vals, axis=tuple(range(1, 1 + extra)))
+        for ax, (got, want) in enumerate(zip(vals.shape[1:], tgt)):
+            if got != want:
+                assert want == 1, (vals.shape, tgt)
+                vals = jnp.sum(vals, axis=1 + ax, keepdims=True)
+        return vals
+
+    width = max(
+        math.prod(lrel.chunk_shape) * lrel.data.dtype.itemsize,
+        math.prod(rrel.chunk_shape) * rrel.data.dtype.itemsize,
+    )
+    rows, blocks = block_rows(e, width)
+
+    def local(keys, ldata, rdata):
+        if blocks == 1:
+            return at_rows(keys, ldata, rdata)
+        (kb,) = _row_blocks((keys,), rows, blocks, (COO_PAD_KEY,))
+        out = jax.lax.map(lambda k: at_rows(k, ldata, rdata), kb)
+        return out.reshape((blocks * rows,) + out.shape[2:])[:e]
+
+    if shards is None:
+        vals = local(ref.keys, lrel.data, rrel.data)
+    else:
+        vals = jax.shard_map(
+            local,
+            mesh=shards.mesh,
+            in_specs=(shards.spec(2), P(), P()),
+            out_specs=shards.spec(1 + len(tgt)),
+            check_vma=False,
+        )(ref.keys, lrel.data, rrel.data)
     return CooRelation(
         ref.keys, vals, ref.extents, ref.owner_dim, ref.shard_offsets
     )
@@ -644,6 +915,7 @@ def _execute_graph(
     fuse_join_agg: bool = True,
     dispatch=None,
     resolutions: Optional[Dict] = None,
+    nnz_shards: Optional[NnzShards] = None,
 ) -> AnyRel:
     """Walk a query graph over chunked relations, lowering each node to XLA
     ops. This is the engine's *lowering primitive*: it runs once per trace
@@ -658,8 +930,19 @@ def _execute_graph(
     ``dispatch`` is a kernels.DispatchTable (None → backend default)
     steering the segment-sum / blocked-matmul hot-spots to a physical
     tier; ``resolutions`` (optional dict) collects ``op[site] → tier``
-    records of every dispatch decision made during the walk."""
+    records of every dispatch decision made during the walk.
+    ``nnz_shards`` names the env's COO relations whose nnz rows the plan
+    puts on a mesh's data axes: their join-aggregates and restricted
+    joins run per shard (``_coo_join_agg``)."""
     memo: Dict[int, AnyRel] = {}
+    sharded = (
+        {id(env[k]) for k in nnz_shards.names if k in env}
+        if nnz_shards is not None
+        else set()
+    )
+
+    def shards_of(rel) -> Optional[NnzShards]:
+        return nnz_shards if id(rel) in sharded else None
 
     def ex(n: fra.Node) -> AnyRel:
         if n.id in memo:
@@ -675,6 +958,13 @@ def _execute_graph(
         if isinstance(lrel, CooRelation) or isinstance(rrel, CooRelation):
             if isinstance(lrel, CooRelation) and isinstance(rrel, CooRelation):
                 raise LoweringError("COO ⋈ COO not supported")
+            if grp is not None:
+                coo = lrel if isinstance(lrel, CooRelation) else rrel
+                fused = _coo_join_agg(
+                    n, grp, lrel, rrel, dispatch, resolutions, shards_of(coo)
+                )
+                if fused is not None:
+                    return fused
             out = _coo_join(n, lrel, rrel, dispatch, resolutions)
             if grp is not None:
                 out = _agg_coo(grp, out)
@@ -741,21 +1031,11 @@ def _execute_graph(
             flat = flat + rel.keys[:, keep[i]].astype(jnp.int32) * stride
             stride *= extents[i]
         num = math.prod(extents)
-        chunk = rel.chunk_shape
-        d = math.prod(chunk)
-        info = {
-            "nnz": rel.nnz, "dim": d, "num_segments": num,
-            "dtype": rel.values.dtype,
-        }
-        impl = kernels.resolve_impl("segment_sum", info, dispatch)
-        _note(resolutions, "segment_sum", f"E={rel.nnz},D={d},S={num}", impl, info)
-        if impl.tier == "jnp":
-            summed = jax.ops.segment_sum(rel.values, flat, num_segments=num)
-        else:
-            msg = rel.values.reshape((rel.nnz, d))
-            summed = impl.fn(msg, flat, num)          # (num, d)
+        summed = _dispatched_segment_sum(
+            rel.values, flat, num, dispatch, resolutions
+        )
         return DenseRelation(
-            summed.reshape(extents + chunk), key_arity=len(extents)
+            summed.reshape(extents + rel.chunk_shape), key_arity=len(extents)
         )
 
     def _ex(n: fra.Node) -> AnyRel:
@@ -825,7 +1105,8 @@ def _execute_graph(
                 lrel, rrel = ex(n.child.left), ex(n.child.right)
                 if isinstance(lrel, DenseRelation) and isinstance(rrel, DenseRelation):
                     return _restricted_join(
-                        n.child, ref, lrel, rrel, dispatch, resolutions
+                        n.child, ref, lrel, rrel, dispatch, resolutions,
+                        shards_of(ref),
                     )
             child = ex(n.child)
             if isinstance(child, CooRelation):

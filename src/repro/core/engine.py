@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import fra, kernels, planner
+from . import compiler, fra, kernels, planner
 from . import rewrite as _rewrite
 from .autodiff import GradientProgram
 from .relation import CooRelation, DenseRelation, pad_coo_nnz
@@ -216,8 +216,15 @@ class Compiled:
         in_shardings: Optional[Tuple[Dict, Dict]] = None,
         pad_nnz: Optional[Dict[str, int]] = None,
         rechunks: Optional[Dict[str, int]] = None,
+        resolutions: Optional[Dict[str, str]] = None,
     ):
         self.lowered = lowered
+        #: the dispatch record: the lowering's, or where sites run per
+        #: shard (``compiler.NnzShards``) the step's own, which its trace
+        #: fills (at the first call)
+        self._resolutions = (
+            lowered.resolutions if resolutions is None else resolutions
+        )
         self._jitted = jitted
         self.donate_names = donate_names
         #: planner.JoinPlan per Join node id — the chosen physical plans.
@@ -287,7 +294,7 @@ class Compiled:
         """``op[site] → tier`` record of every kernel-dispatch decision
         taken while lowering (e.g. ``segment_sum[E=320000,D=32,S=20000]``
         → ``'pallas'``)."""
-        return dict(self.lowered.resolutions)
+        return dict(self._resolutions)
 
     @property
     def placements(self) -> Dict[str, Dict[str, Optional[int]]]:
@@ -568,6 +575,7 @@ class Lowered:
         n_devices: Optional[int] = None,
         committed: Optional[Dict[str, P]] = None,
         stats: Optional[Dict[str, planner.RelationStats]] = None,
+        traced: bool = False,
     ) -> Compiled:
         """plan_query → in_shardings → jax.jit.
 
@@ -603,7 +611,15 @@ class Lowered:
         On a mesh of more than one device the step runs under
         ``kernels.table_for_mesh(self.dispatch, mesh)`` (no ``pallas``
         tier), and the returned Compiled's ``dispatch``/``resolutions``
-        say so.
+        say so. Where the plan puts a mesh's data axes on a COO's nnz
+        rows, that relation's join-aggregates run per shard inside
+        ``shard_map`` with the table's per-shard tiers
+        (``compiler.NnzShards``), and their outputs come back with their
+        rows owner-sharded.
+        ``traced`` says the step runs inside a caller's trace (a user's
+        own ``jax.jit``): the caller's operands keep their shardings, so
+        the step places no inputs (no in_shardings, no reshard); only the
+        per-shard join-aggregates take the mesh.
         """
         table = kernels.table_for_mesh(self.dispatch, mesh)
         if table != self.dispatch:
@@ -615,6 +631,7 @@ class Lowered:
                 n_devices=n_devices,
                 committed=committed,
                 stats=stats,
+                traced=traced,
             )
         donate = tuple(sorted(donate))
         geo = (
@@ -636,7 +653,7 @@ class Lowered:
         stats_key = _stats_key(stats)
         key = (
             mesh, axis, donate, mem_budget, n_devices, geo, committed_key,
-            stats_key,
+            stats_key, traced,
         )
         hit = self._compiled.get(key)
         if hit is not None:
@@ -690,10 +707,14 @@ class Lowered:
             shardings = None
             pad_nnz: Dict[str, int] = {}
             coo_pins: Dict[str, CooRelation] = {}
+            nnz_shards = None
+            step_resolutions = None
             if mesh is not None:
                 sh_don: Dict[str, AnyRel] = {}
                 sh_kept: Dict[str, AnyRel] = {}
                 for k, rel in self.abstract_env.items():
+                    if traced and not isinstance(rel, CooRelation):
+                        continue  # the caller's trace places dense inputs
                     sharding, pad = self._rel_sharding(
                         k, rel, input_specs.get(k), mesh
                     )
@@ -709,8 +730,22 @@ class Lowered:
                         # per-shard local segsum + psum the plan costed)
                         # regardless of how XLA would re-place the operands.
                         coo_pins[k] = sharding
-                jit_kwargs["in_shardings"] = (sh_don, sh_kept, None)
-                shardings = (sh_don, sh_kept)
+                if traced:
+                    coo_pins = {}
+                else:
+                    jit_kwargs["in_shardings"] = (sh_don, sh_kept, None)
+                    shardings = (sh_don, sh_kept)
+                sharded = frozenset(
+                    k for k, sh in {**sh_don, **sh_kept}.items()
+                    if isinstance(sh, CooRelation) and tuple(sh.values.spec)
+                )
+                if sharded and geo.data_size > 1:
+                    nnz_shards = compiler.NnzShards(
+                        mesh, geo.data_axes, sharded
+                    )
+                    # the per-shard sites resolve under the per-shard
+                    # tiers: the step records them as it is traced
+                    step_resolutions = kernels.ResolutionLog()
 
             def step(donated_env: Env, kept_env: Env, seed):
                 env = dict(kept_env)
@@ -727,7 +762,8 @@ class Lowered:
                         rel.shard_offsets,
                     )
                 return engine._execute(
-                    env, seed, dispatch=table, program=program
+                    env, seed, dispatch=table, program=program,
+                    resolutions=step_resolutions, nnz_shards=nnz_shards,
                 )
 
             compiled = Compiled(
@@ -741,6 +777,7 @@ class Lowered:
                 shardings,
                 pad_nnz,
                 rechunks,
+                step_resolutions,
             )
         self._compiled[key] = compiled
         while len(self._compiled) > _MAX_COMPILED:
@@ -806,11 +843,18 @@ class Lowered:
         an upstream producer changed its placement — trigger a re-plan,
         which then charges the rechunk and becomes the new record.
 
+        An ``env`` that an outer trace is tracing (a user's own
+        ``jax.jit``) compiles with ``traced=True``: its layouts are the
+        caller's, so none are threaded.
+
         This is the compile entry the ``Database`` session and the
         relational operator layer step through."""
         donate = tuple(sorted(donate))
-        base = (mesh, axis, donate, mem_budget, _stats_key(stats))
-        committed = _committed_layouts(env) if mesh is not None else {}
+        traced = not _trace_clean(env)
+        base = (mesh, axis, donate, mem_budget, _stats_key(stats), traced)
+        committed = (
+            _committed_layouts(env) if mesh is not None and not traced else {}
+        )
         prev = self._auto.get(base)
         if prev is not None and all(
             _norm_spec(prev.planned_spec(name)) == _norm_spec(spec)
@@ -825,6 +869,7 @@ class Lowered:
             mem_budget=mem_budget,
             committed=committed or None,
             stats=stats,
+            traced=traced,
         )
         self._auto[base] = compiled
         while len(self._auto) > _MAX_COMPILED:
@@ -1126,14 +1171,15 @@ class RAEngine:
         dispatch: Optional[kernels.DispatchTable] = None,
         resolutions: Optional[Dict[str, str]] = None,
         program: Optional[Program] = None,
+        nnz_shards=None,
     ):
         """Walk the program's FRA graph(s) over ``env`` (eagerly or under
         a jax trace). ``program`` overrides the engine's own program —
         the handle a ``Lowered`` uses to execute the *rewritten* program
         its cache entry lowered (core/rewrite.py) while sharing this
-        engine's trace counter and fuse flag."""
-        from . import compiler
-
+        engine's trace counter and fuse flag. ``nnz_shards``
+        (``compiler.NnzShards``) names the COO relations whose
+        join-aggregates run per shard."""
         self.trace_count += 1
         prog = self.program if program is None else program
         if not isinstance(prog, GradientProgram):
@@ -1145,6 +1191,7 @@ class RAEngine:
                 fuse_join_agg=self.fuse_join_agg,
                 dispatch=dispatch,
                 resolutions=resolutions,
+                nnz_shards=nnz_shards,
             )
 
         fwd_cache: Env = {}
@@ -1155,6 +1202,7 @@ class RAEngine:
             fuse_join_agg=self.fuse_join_agg,
             dispatch=dispatch,
             resolutions=resolutions,
+            nnz_shards=nnz_shards,
         )
         if seed is None:
             if not (isinstance(out, DenseRelation) and out.key_arity == 0):
@@ -1168,7 +1216,8 @@ class RAEngine:
         # rjp_ablation relies on it).
         grads = {
             name: compiler._execute_graph(
-                rootn, genv, dispatch=dispatch, resolutions=resolutions
+                rootn, genv, dispatch=dispatch, resolutions=resolutions,
+                nnz_shards=nnz_shards,
             )
             for name, rootn in prog.grads.items()
         }

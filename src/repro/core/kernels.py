@@ -364,12 +364,21 @@ class DispatchTable:
 
     backend: str
     entries: Tuple[Tuple[str, Tuple[str, ...]], ...]  # sorted by op name
+    #: the entries inside a ``shard_map`` body, where each device runs
+    #: its own shard as on one chip (``table_for_mesh``); () = ``entries``
+    shard_entries: Tuple[Tuple[str, Tuple[str, ...]], ...] = ()
 
     def tiers(self, op: str) -> Tuple[str, ...]:
         for name, tiers in self.entries:
             if name == op:
                 return tiers
         return ("jnp",)
+
+    def per_shard(self) -> "DispatchTable":
+        """The table for ops that run per shard inside ``shard_map``."""
+        if not self.shard_entries:
+            return self
+        return DispatchTable(self.backend, self.shard_entries)
 
     def describe(self) -> str:
         body = ", ".join(
@@ -394,15 +403,19 @@ def table_for_mesh(table: DispatchTable, mesh) -> DispatchTable:
     partitioner cannot split a Pallas TPU kernel (a Mosaic call must sit
     inside a ``shard_map``), so on a mesh of more than one device the
     ``pallas`` tier leaves every op's order and the rest of it applies:
-    the default TPU table becomes ``jnp`` for all three ops. The engine
-    applies this once, where ``Lowered.compile`` meets the mesh."""
+    the default TPU table becomes ``jnp`` for all three ops. The ops that
+    run per shard inside ``shard_map`` (the nnz-sharded join-aggregate's
+    gather and Σ, ``compiler._coo_join_agg``) keep the table as given:
+    ``per_shard()``. The engine applies this once, where
+    ``Lowered.compile`` meets the mesh."""
     if mesh is None or mesh.size <= 1:
         return table
     entries = tuple(
         (op, tuple(t for t in tiers if t != "pallas") or ("jnp",))
         for op, tiers in table.entries
     )
-    return DispatchTable(table.backend, entries)
+    shard = table.shard_entries or table.entries
+    return DispatchTable(table.backend, entries, shard if shard != entries else ())
 
 
 def make_table(spec=None, backend: Optional[str] = None) -> DispatchTable:

@@ -33,6 +33,7 @@ all-reduce the chosen plan implies.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -315,6 +316,7 @@ def plan_join(
     committed_dims: Tuple[Optional[Dict], Optional[Dict]] = (None, None),
     coo_edge_cut: Tuple[Optional[float], Optional[float]] = (None, None),
     sum_out_stat: bool = False,
+    coo_message_bytes: float = 0.0,
 ) -> JoinPlan:
     """Pick the cheapest *feasible* physical plan by bytes moved per
     device, exactly the way the paper describes the database optimizer
@@ -359,6 +361,16 @@ def plan_join(
     marks ``sum_out_bytes`` as catalog-backed: the defensive dense-side
     cap on the segment-grid estimate is then skipped — the statistics
     already bound the Σ output by the real key domain.
+
+    ``coo_message_bytes`` is what a COO join's gather and ⊗ produce (nnz
+    rows × the dense side's row). Replicating the COO makes every device
+    gather all of them, where sharding its nnz rows gathers 1/n a device.
+    The costs here are bytes exchanged between devices, which that work
+    is not, so it bounds the plan instead: ``data:replicate`` is feasible
+    only while one device's share of the work, all the messages, is
+    within the per-device budget. A join-aggregate over tens of millions
+    of edges then keeps its nnz rows on the data axes whatever its
+    scatter costs.
     """
     geo = geometry or MeshGeometry.single(n_devices)
     n_model = max(1, geo.model_size)
@@ -413,7 +425,11 @@ def plan_join(
         # feasibility mirrors the model axis: a candidate must fit every
         # relation it replicates within the per-device budget
         dcosts: Dict[str, float] = {}
-        if left_bytes <= mem_budget and right_bytes <= mem_budget:
+        if (
+            left_bytes <= mem_budget
+            and right_bytes <= mem_budget
+            and coo_message_bytes <= mem_budget
+        ):
             # no batch parallelism: both inputs replicated over the axes
             dcosts["data:replicate"] = (left_bytes + right_bytes) * frac_d
         if coo_l:
@@ -587,6 +603,18 @@ def _coo_owner_survives(
     except ValueError:
         return False
     return any(isinstance(c, In) and c.idx == pos for c in agg.grp.comps)
+
+
+def _coo_message_bytes(join: fra.Join, env: Dict[str, object]) -> float:
+    """Bytes a COO ⋈ Dense join's gather produces, nnz rows × the dense
+    side's row, where both sides are base relations; else 0."""
+    rels = [env.get(_leaf_name(c)) for c in (join.left, join.right)]
+    coo = [r for r in rels if isinstance(r, CooRelation)]
+    dense = [r for r in rels if isinstance(r, DenseRelation)]
+    if len(coo) != 1 or len(dense) != 1:
+        return 0.0
+    d = dense[0]
+    return float(coo[0].nnz) * math.prod(d.chunk_shape) * d.data.dtype.itemsize
 
 
 def _leaf_name(n) -> Optional[str]:
@@ -879,6 +907,7 @@ def plan_query(
                 edge_cut_of(node.right, "right", node, agg),
             ),
             sum_out_stat=agg is not None and agg.id in stat_aggs,
+            coo_message_bytes=_coo_message_bytes(node, env),
         )
     return plans
 

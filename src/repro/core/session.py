@@ -573,12 +573,14 @@ class Database:
 
     def _step_mesh(self, *trees):
         """Mesh a step over inputs ``trees`` should compile against: the
-        session mesh — or the ambient mesh of an enclosing activated
-        session — when the inputs are concrete; None when an outer trace
-        is tracing them (the engine's ``_trace_clean`` probe is the single
-        source of that rule)."""
+        session mesh, traced inputs or not (a step inside a caller's
+        trace places no inputs, ``Lowered.compile(traced=True)``); else
+        the ambient mesh of an enclosing activated session when the
+        inputs are concrete, None when an outer trace is tracing them
+        (the engine's ``_trace_clean`` probe is the single source of that
+        rule)."""
         if self.mesh is not None:
-            return self.mesh if _engine._trace_clean(*trees) else None
+            return self.mesh
         return _engine._ambient_mesh(*trees)
 
     @contextlib.contextmanager

@@ -41,6 +41,17 @@ NAMES = (
     ENGINE_PLAN, ENGINE_CALL, ENGINE_DISPATCH,
 )
 
+# Device-op scopes (``jax.named_scope``): the name in the op metadata of
+# the device operations a lowering emits, beside the kernels' own
+# ``pallas_call`` names.
+
+#: the fused join-aggregate (``compiler._coo_join_agg``): its block loop,
+#: the gathers, ⊗ and Σ in it.
+JOIN_AGG = "join_agg"
+#: the collectives of a sharded join-aggregate: the all-gather of its
+#: table and the exchange of its partial sums (``compiler.exchange``).
+EXCHANGE = "exchange"
+
 #: ``with span(NAME):`` around part of a function.
 span = jax.profiler.TraceAnnotation
 

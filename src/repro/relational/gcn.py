@@ -2,10 +2,13 @@
 
   h'_dst = Σ_{(src,dst,w) ∈ Edge} w · h_src
 
-Forward: Edge ⋈ Node (gather) + Σ-by-dst (segment sum). Backward — both
-∂/∂h (the reversed-edge convolution) and ∂/∂w (per-edge h·g dot) — is the
-RA-autodiff-generated query, compiled to gather + segment-sum. The Pallas
-segsum kernel is the TPU hot path for the Σ (see kernels/segsum).
+Forward: Edge ⋈ Node (gather) + Σ-by-dst (segment sum), lowered as one
+fused join-aggregate that runs in blocks of edges when their messages
+would not fit one (``compiler._coo_join_agg``). Backward — both ∂/∂h (the
+reversed-edge convolution) and ∂/∂w (per-edge h·g dot, E values) — is
+the RA-autodiff-generated query, compiled the same way. The Pallas
+gather and segsum kernels are the TPU hot path (kernels/gather,
+kernels/segsum).
 
 Forward and backward step through the ambient ``Database`` session
 (``core.session.current()``): the program is built once, lowered per
@@ -15,8 +18,11 @@ across training steps. Under an activated mesh-bearing session
 the relations on the session's (data × model) mesh, including the edge
 CooRelation's nnz row dimension over the data axes
 (``data:shard_nnz_*`` plans): the gather join and Σ-by-dst then run
-per-shard with the planned scatter collective, so the largest array in
-the program — the edge list — never has to fit one device.
+per shard inside ``shard_map``, in blocks of edges, and reduce-scatter
+their partial sums to the row owners, so neither the largest array in
+the program — the edge list — nor the edges' messages ever has to fit
+one device, and the convolution's output comes back owner-sharded by
+rows. This holds inside a user's own ``jax.jit`` too.
 ``partitioned_edges`` pre-sorts edges by dst (owner partition), which
 the planner prices at its edge-cut estimate — or, when the session's
 catalog tracks the edge relation's statistics, at the measured

@@ -7,6 +7,10 @@ non-divisible nnz, reshard accounting — and, under the tier1-spmd lane's
 nnz-sharded edge relation on the 4×2 host mesh matches the single-device
 oracle to 1e-5."""
 
+import pathlib
+import subprocess
+import sys
+import textwrap
 import warnings
 
 import jax
@@ -259,6 +263,22 @@ def test_owner_partition_sorts_pads_and_records_offsets():
     leaves, treedef = jax.tree_util.tree_flatten(rel)
     back = jax.tree_util.tree_unflatten(treedef, leaves)
     assert back.owner_dim == 1 and back.shard_offsets == rel.shard_offsets
+
+
+@pytest.mark.parametrize("high", [70_000, 2_449_029, 2**31 - 1])
+def test_owner_partition_is_a_stable_sort_of_wide_keys(high):
+    """Owner keys past 16 bits sort in numpy's stable order: rows with
+    equal keys keep their order (each row's value is its position)."""
+    rng = np.random.default_rng(3)
+    nnz = 5000
+    dst = rng.integers(0, high, size=nnz)
+    dst[::7] = dst[0]                        # runs of equal keys
+    keys = np.stack([np.arange(nnz), dst], axis=1).astype(np.int32)
+    vals = np.arange(nnz, dtype=np.float32)
+    rel = owner_partition(CooRelation(keys, vals, (nnz, high)), 4, dim=1)
+    order = np.argsort(dst, kind="stable")
+    np.testing.assert_array_equal(np.asarray(rel.keys)[:nnz], keys[order])
+    np.testing.assert_array_equal(np.asarray(rel.values)[:nnz], vals[order])
 
 
 def test_pad_coo_nnz_is_numerically_inert():
@@ -553,3 +573,143 @@ def test_reshard_stats_count_committed_moves_and_warn_once():
         warnings.simplefilter("error", ReshardWarning)
         comp3(env_rep)
     assert comp3.counters["reshard"]["last_call_bytes"] == 0
+
+
+# ---------------------------------------------------------------------------
+# Fused join-aggregate: Σ(COO ⋈ Dense) in blocks of nnz rows, per shard
+# ---------------------------------------------------------------------------
+
+
+def _small_blocks(monkeypatch, rows: int, d: int) -> None:
+    """Blocks of ``rows`` nnz rows at width ``d`` (f32)."""
+    from repro.core import compiler
+
+    monkeypatch.setattr(compiler, "JOIN_AGG_BLOCK_BYTES", rows * d * 4)
+    monkeypatch.setattr(compiler, "BLOCK_ROWS_QUANTUM", 8)
+
+
+@pytest.mark.parametrize("nnz", [1000, 1003])
+def test_blocked_join_agg_matches_one_block(monkeypatch, nnz):
+    """With the block small, the forward Σ_dst(Edge ⋈ Node), ∂Node (the
+    reversed-edge Σ) and ∂Edge (the per-edge dot) run in ≥ 3 blocks, a
+    ragged last one among them at 1003 rows, and match the one-block
+    lowering to 1e-5, -1 pad rows included."""
+    rng = np.random.default_rng(11)
+    env = gcn_env(rng, n=50, nnz=nnz, d=8)
+    keys = np.asarray(env["Edge"].keys).copy()
+    keys[-9:] = COO_PAD_KEY
+    env["Edge"] = CooRelation(jnp.asarray(keys), env["Edge"].values, (50, 50))
+    prog = gcn_grad_prog()
+    out_1, grads_1 = RAEngine(prog).lower(env).compile()(env)
+    _small_blocks(monkeypatch, 296, 8)
+    low = RAEngine(prog).lower(env)
+    notes = [k for k in low.resolutions if k.startswith("join_agg[")]
+    assert notes and all("blocks=4" in k for k in notes), notes
+    out_b, grads_b = low.compile()(env)
+    np.testing.assert_allclose(out_b.data, out_1.data, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        grads_b["Node"].data, grads_1["Node"].data, rtol=1e-5, atol=1e-5
+    )
+    np.testing.assert_allclose(
+        grads_b["Edge"].values, grads_1["Edge"].values, rtol=1e-5, atol=1e-5
+    )
+    assert np.all(np.asarray(grads_b["Edge"].values)[-9:] == 0.0)
+
+
+def test_replicating_edges_is_infeasible_when_their_messages_bust_the_budget():
+    """Replicating a COO makes every device produce all its messages (nnz
+    × the dense row): over the per-device budget the nnz rows stay on the
+    data axes even where the scatter costs more than replication."""
+    env = {"Edge": _coo(100_000), "Node": DenseRelation(jnp.zeros((64, 8), jnp.float32), 1)}
+    q = gcn_query()
+    (free,) = plan_query(q, env, 2, geometry=GEO, mem_budget=1e9).values()
+    assert "data:replicate" in free.costs
+    # 100,000 edges × 8 × 4 B = 3.2 MB of messages over a 3 MB budget
+    (tight,) = plan_query(q, env, 2, geometry=GEO, mem_budget=3e6).values()
+    assert "data:replicate" not in tight.costs
+    assert tight.data_kind == "data:shard_nnz_left"
+
+
+_SHARDED_GCN_SCRIPT = textwrap.dedent(
+    """
+    import os, re, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import repro
+    from repro.core import compiler
+    from repro.core.relation import pad_coo_nnz
+    from repro.launch.mesh import make_host_mesh
+    from repro.relational import gcn_conv, partitioned_edges, rel_linear
+
+    case = sys.argv[1]
+    rng = np.random.default_rng(5)
+    n, nnz, d = 64, 1800, 8
+    keys = np.stack([rng.integers(0, n, nnz), rng.integers(0, n, nnz)], 1)
+    w = (rng.normal(size=nnz) / 10).astype(np.float32)
+    edge = partitioned_edges(keys.astype(np.int32), w, n, 4)
+    if case == "empty_shard":
+        # the last quarter of the nnz rows is padding: a shard with no edges
+        edge = pad_coo_nnz(edge, 2400)
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    W = jnp.asarray(rng.normal(size=(d, 5)), jnp.float32)
+
+    def loss(W, x, k, w):
+        h = jax.nn.relu(rel_linear(gcn_conv(x, k, w), W))
+        return jnp.sum(gcn_conv(h, k, w) ** 2)
+
+    l1, g1 = jax.jit(jax.value_and_grad(loss))(W, x, edge.keys, edge.values)
+    compiler.JOIN_AGG_BLOCK_BYTES = 100 * d * 4
+    compiler.BLOCK_ROWS_QUANTUM = 16
+    if case == "exchange":
+        compiler.exchange = lambda part, axes, *, scatter: part[: part.shape[0] // 4]
+    mesh = make_host_mesh(model=1)
+    k4 = jax.device_put(edge.keys, NamedSharding(mesh, P("data", None)))
+    w4 = jax.device_put(edge.values, NamedSharding(mesh, P("data")))
+    db = repro.Database(mesh=mesh)
+    with db.activate():
+        step = jax.jit(jax.value_and_grad(loss))
+        l4, g4 = step(W, x, k4, w4)
+        text = step.lower(W, x, k4, w4).compile().as_text()
+    sites = [k for k in db.resolutions() if k.startswith("join_agg[")]
+    e4 = edge.nnz // 4
+    assert sites and all(f"E={e4}," in k and "blocks=1," not in k for k in sites), sites
+    same = np.allclose(l4, l1, rtol=1e-5) and np.allclose(g4, g1, rtol=1e-5, atol=1e-5)
+    if case == "exchange":
+        assert not same, (l1, l4)
+    else:
+        assert same, (l1, l4, np.abs(g4 - g1).max())
+        assert "reduce-scatter" in text
+        for rows, cols in re.findall(r"f32\\[(\\d+),(\\d+)", text):
+            assert not (int(rows) in (edge.nnz, e4) and int(cols) > 1), (rows, cols)
+    print("SHARDED-GCN-OK")
+    """
+)
+
+
+@pytest.mark.spmd
+@pytest.mark.parametrize("case", ["edges", "empty_shard", "exchange"])
+def test_gcn_conv_in_user_jit_runs_per_shard_on_four_devices(case):
+    """On four virtual CPU devices, ``gcn_conv`` inside a user's jit under
+    ``Database(mesh=...)`` on ``partitioned_edges`` runs its join-aggregates
+    per shard in blocks (``join_agg`` notes a quarter of the edges and
+    several blocks) and matches one device, a shard of padding alone
+    included; the step holds a reduce-scatter and no edges × width array.
+    With the exchange of partial sums replaced by each shard's own part,
+    it disagrees."""
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    r = subprocess.run(
+        [sys.executable, "-c", _SHARDED_GCN_SCRIPT, case],
+        capture_output=True,
+        text=True,
+        env={
+            "PYTHONPATH": str(repo / "src"),
+            "PATH": "/usr/bin:/bin",
+            "JAX_PLATFORMS": "cpu",
+        },
+        cwd=str(repo),
+        timeout=600,
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "SHARDED-GCN-OK" in r.stdout

@@ -363,10 +363,50 @@ def test_table_for_mesh_drops_pallas_on_multi_device_meshes(size, want):
     assert K.table_for_mesh(forced, mesh).tiers("segment_sum") == kept
 
 
+def test_table_for_mesh_keeps_the_table_for_ops_run_per_shard():
+    """The per-shard view of a multi-device table is the table as given:
+    inside shard_map each device runs its shard as on one chip."""
+    from types import SimpleNamespace
+
+    t = K.default_table("tpu")
+    four = K.table_for_mesh(t, SimpleNamespace(size=4))
+    assert four.per_shard() == t
+    assert K.table_for_mesh(four, SimpleNamespace(size=4)) == four
+    assert K.table_for_mesh(t, SimpleNamespace(size=1)).per_shard() == t
+    # a table with nothing to drop keeps no separate per-shard view
+    jnp_only = K.make_table("jnp", backend="tpu")
+    assert K.table_for_mesh(jnp_only, SimpleNamespace(size=4)) == jnp_only
+
+
+ARXIV_NODES = 169_343
+ARXIV_EDGES = 1_166_243 + ARXIV_NODES
+
+
+def test_join_agg_is_one_block_at_arxiv_shape():
+    """At ogbn-arxiv shape every join-aggregate of the GCN's step fits one
+    block (1,335,586 edges × 256 × 4 B = 1.37 GB of messages): its note
+    says so, and the call is lowered as one gather, ⊗ and Σ."""
+    sds = jax.ShapeDtypeStruct
+    env = {
+        "Edge": CooRelation(sds((ARXIV_EDGES, 2), jnp.int32),
+                            sds((ARXIV_EDGES,), jnp.float32),
+                            (ARXIV_NODES, ARXIV_NODES)),
+        "Node": DenseRelation(sds((ARXIV_NODES, 256), jnp.float32), 1),
+    }
+    low = RAEngine(ra_autodiff(gcn_square_loss())).lower(
+        env, dispatch=K.make_table(None, backend="tpu"))
+    notes = {k: v for k, v in low.resolutions.items() if k.startswith("join_agg[")}
+    assert notes and set(notes.values()) == {"pallas"}, low.resolutions
+    assert all(k.split("#")[0] == f"join_agg[E={ARXIV_EDGES},D=256,"
+               f"S={ARXIV_NODES},blocks=1]" for k in notes), notes
+    assert any(k.startswith(f"segment_sum[E={ARXIV_EDGES},") for k in low.resolutions)
+
+
 _MESH_TABLE_SCRIPT = textwrap.dedent(
     """
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -390,11 +430,21 @@ _MESH_TABLE_SCRIPT = textwrap.dedent(
     # a TPU table lowers to Pallas sites (abstractly: nothing runs) ...
     low = RAEngine(prog).lower(env, dispatch=K.make_table(None, backend="tpu"))
     assert set(low.resolutions.values()) == {"pallas"}, low.resolutions
-    # ... and a four-device mesh compiles and runs them as jnp
+    # ... a four-device mesh compiles the sites XLA's partitioner splits
+    # as jnp, and keeps Pallas only for the sites that run per shard
+    # inside shard_map: here every site, the edges being nnz-sharded
     comp = low.compile(mesh=make_host_mesh(model=1))
     assert "pallas" not in comp.dispatch.describe(), comp.dispatch
-    assert set(comp.resolutions.values()) == {"jnp"}, comp.resolutions
+    assert comp.dispatch.per_shard().tiers("gather_join")[0] == "pallas"
+    # the step records its per-shard sites as it is traced (abstractly
+    # here: a TPU Pallas step does not lower for the CPU)
+    jax.eval_shape(comp._jitted, {}, env, None)
+    assert set(comp.resolutions.values()) == {"pallas"}, comp.resolutions
+    assert any(k.startswith("join_agg[E=16,") for k in comp.resolutions)
+    # the backend's own table runs them on the mesh as on one device
+    comp = RAEngine(prog).lower(env).compile(mesh=make_host_mesh(model=1))
     out, grads = comp(env)
+    assert set(comp.resolutions.values()) == {"jnp"}, comp.resolutions
     ref_out, ref_grads = RAEngine(prog).lower(env).compile()(env)
     np.testing.assert_allclose(np.asarray(out.data), np.asarray(ref_out.data),
                                rtol=1e-5, atol=1e-5)
